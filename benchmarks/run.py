@@ -6,7 +6,8 @@
   bench_kernels     -> kernel block tuning curve (VMEM occupancy model)
   bench_serve       -> paged vs slot vs static batching under staggered load
 
-Prints ``name,us_per_call,derived`` CSV rows.  Modules that populate a
+Prints ``name,us_per_call,derived`` CSV rows and exits non-zero when any
+module failed (its row reads ``<name>_FAILED``).  Modules that populate a
 ``json_summary`` dict additionally get it written to ``BENCH_<name>.json``
 (machine-readable: tok/s, latency percentiles, HBM high-water) so the perf
 trajectory is tracked across PRs.
@@ -18,7 +19,7 @@ import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
     import benchmarks.bench_autotune as b_autotune
     import benchmarks.bench_bots as b_bots
     import benchmarks.bench_kernels as b_kernels
@@ -29,6 +30,7 @@ def main() -> None:
             "kernels": b_kernels, "serve": b_serve}
     only = sys.argv[1] if len(sys.argv) > 1 else None
     print("name,us_per_call,derived")
+    failed = []
     for name, mod in mods.items():
         if only and only != name:
             continue
@@ -43,10 +45,14 @@ def main() -> None:
                     json.dump(summary, f, indent=2)
                     f.write("\n")
                 print(f"# wrote {path}", flush=True)
-        except Exception as e:  # keep the harness robust
+        except Exception as e:  # run the other modules, then fail
+            failed.append(name)
             print(f"{name}_FAILED,NaN,{type(e).__name__}: {str(e)[:80]}")
         print(f"# {name} finished in {time.time()-t0:.1f}s", flush=True)
+    if failed:
+        print(f"# failed: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -3,7 +3,9 @@
 Parses post-SPMD HLO text (``compiled.as_text()``) into per-region counters:
 
   * flops          — 2·M·N·K for dots (from inline operand shapes +
-                     contracting dims), element count for everything else
+                     contracting dims) and for the convolutions the TPU
+                     backend lowers matmuls to (padding taps excluded),
+                     element count for everything else
   * bytes          — operand + output bytes per instruction
   * collective_bytes / collective ops census (all-gather, all-reduce,
     reduce-scatter, all-to-all, collective-permute), with ring-cost link
@@ -39,13 +41,38 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-_INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\([^)]*\)|[\w\[\]{},\s]*?)\s*"
-    r"([\w\-]+)\((.*)$")
+_LHS_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*")
+_OPCODE_RE = re.compile(r"\s*([\w\-]+)\((.*)$")
 _METADATA_RE = re.compile(r'op_name="([^"]*)"')
 _REGION_RE = re.compile(r"R\.([\w.]+)")
 _CALLS_RE = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
 _REPLICA_RE = re.compile(r"replica_groups=\{?\[?(\d+),(\d+)\]?")
+
+
+def _parse_instr(line: str):
+    """``(name, out_type, opcode, rest)`` of one HLO instruction line, or
+    None.  The type is scanned bracket-balanced rather than by a character
+    class, because TPU layouts carry tiling and memory-space annotations
+    inside the braces (``bf16[4,2048]{1,0:T(4,128)(2,1)S(1)}``) and tuple
+    types nest them (``(f32[8]{0:T(128)}, u32[]{:S(2)})``)."""
+    m = _LHS_RE.match(line)
+    if not m:
+        return None
+    start, depth = m.end(), 0
+    for end in range(start, len(line)):
+        ch = line[end]
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        elif ch == " " and depth == 0:
+            break
+    else:
+        return None
+    op = _OPCODE_RE.match(line, end)
+    if not op:
+        return None
+    return m.group(1), line[start:end], op.group(1), op.group(2)
 
 
 def _shape_bytes(type_str: str) -> int:
@@ -193,6 +220,59 @@ def _dot_flops(out_type: str, rest: str, symbols: Dict[str, str]) -> float:
     return 2.0 * _shape_elems(out_type) * k
 
 
+def _window_taps(out_n: int, in_n: int, size: int, lo: int,
+                 stride: int) -> int:
+    """(output, kernel tap) pairs along one spatial dim that read real
+    input, not padding."""
+    taps = 0
+    for o in range(out_n):
+        start = o * stride - lo
+        taps += max(0, min(start + size, in_n) - max(start, 0))
+    return taps
+
+
+def _conv_flops(out_type: str, rest: str, symbols: Dict[str, str]) -> float:
+    """2 * multiply-adds of one ``convolution``, padding taps excluded.
+
+    The TPU backend lowers matmuls to convolutions: ``bf_io->bf`` is a plain
+    dot, and a dot with an extra output axis can become a windowed
+    convolution over a size-1 spatial dim padded to the window
+    (``bf0_i0o->b0f, window={size=32 pad=31_31}``), where all but one tap
+    per output reads padding.  Each output element reduces over the rhs
+    input features (per feature group) times its real window taps."""
+    ops = _split_operands(rest)
+    m = re.search(r"dim_labels=(\w+)_(\w+)->(\w+)", rest)
+    if len(ops) < 2 or not m:
+        return float(_shape_elems(out_type))
+    lhs_l, rhs_l, out_l = m.groups()
+    lhs = _first_shape_dims(_operand_type(ops[0], symbols))
+    rhs = _first_shape_dims(_operand_type(ops[1], symbols))
+    out = _first_shape_dims(out_type)
+    if (len(lhs), len(rhs), len(out)) != (len(lhs_l), len(rhs_l),
+                                         len(out_l)) or "i" not in rhs_l:
+        return float(_shape_elems(out_type))
+    k = rhs[rhs_l.index("i")]
+    w = re.search(r"window=\{([^}]*)\}", rest)
+    fields = dict(f.split("=", 1) for f in w.group(1).split()) if w else {}
+    n_sp = sum(ch.isdigit() for ch in rhs_l)
+    sizes = [int(x) for x in fields.get("size", "").split("x") if x]
+    strides = [int(x) for x in fields.get("stride", "").split("x") if x]
+    pads = [int(x.split("_")[0]) for x in fields.get("pad", "").split("x")
+            if x]
+    frac = 1.0
+    for d in range(n_sp):
+        o_n = out[out_l.index(str(d))]
+        taps = _window_taps(o_n, lhs[lhs_l.index(str(d))],
+                            sizes[d] if d < len(sizes) else 1,
+                            pads[d] if d < len(pads) else 0,
+                            strides[d] if d < len(strides) else 1)
+        frac *= taps / max(o_n, 1)
+    g = re.search(r"feature_group_count=(\d+)", rest)
+    if g:
+        k //= max(int(g.group(1)), 1)
+    return 2.0 * _shape_elems(out_type) * k * frac
+
+
 def _collective_cost(opcode: str, rest: str, out_type: str,
                      symbols: Dict[str, str]):
     """(shard_bytes, ring_link_bytes) for one collective instruction."""
@@ -227,7 +307,8 @@ def _trip_count(cond_lines: list[str]) -> int:
     """Extract the while trip count from its condition computation."""
     consts = {}
     for ln in cond_lines:
-        m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*s\d+\[\]\s*constant\((\d+)\)", ln)
+        m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*s\d+\[\](?:\{[^}]*\})?\s*"
+                     r"constant\((\d+)\)", ln)
         if m:
             consts[m.group(1)] = int(m.group(2))
     for ln in cond_lines:
@@ -283,9 +364,9 @@ class HloCost:
         root = ""
         for line in self.computations.get(comp, ()):
             if line.strip().startswith("ROOT"):
-                m = _INSTR_RE.match(line)
-                if m:
-                    root = m.group(3)
+                parsed = _parse_instr(line)
+                if parsed:
+                    root = parsed[2]
                 break
         self._root_cache[comp] = root
         return root
@@ -296,18 +377,18 @@ class HloCost:
             return self._symbol_cache[comp]
         table: Dict[str, str] = {}
         for line in self.computations.get(comp, ()):
-            m = _INSTR_RE.match(line)
-            if m:
-                table[m.group(1)] = m.group(2)
+            parsed = _parse_instr(line)
+            if parsed:
+                table[parsed[0]] = parsed[1]
         self._symbol_cache[comp] = table
         return table
 
     def _cost_instr(self, line: str, symbols: Dict[str, str],
                     in_loop: bool = False):
-        m = _INSTR_RE.match(line)
-        if not m:
+        parsed = _parse_instr(line)
+        if not parsed:
             return None
-        name, out_type, opcode, rest = m.groups()
+        name, out_type, opcode, rest = parsed
         c = Counters(ops=1)
         meta = _METADATA_RE.search(line)
         region = ""
@@ -324,8 +405,9 @@ class HloCost:
         in_bytes = sum(_shape_bytes(_operand_type(o, symbols))
                        for o in ops_list)
 
-        if opcode == "dot":
-            c.flops = _dot_flops(out_type, rest, symbols)
+        if opcode in ("dot", "convolution"):
+            c.flops = (_dot_flops if opcode == "dot" else _conv_flops)(
+                out_type, rest, symbols)
             c.bytes = in_bytes + out_bytes
         elif opcode == "fusion" and called and (
                 self._fusion_root_opcode(called[0]) in

@@ -1,7 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels, with model-layout
-adapters and the interpret switch (CPU container -> interpret=True; real TPU
--> compiled).  The tuner's kernel knobs (block sizes, time tiles) surface
-here as keyword args fed from RegionConfig.
+adapters and the interpret switch (CPU backend -> interpret=True; TPU ->
+compiled), decided per call so importing this module never touches the
+backend.  The tuner's kernel knobs (block sizes, time tiles) surface here as
+keyword args fed from RegionConfig.
 """
 from __future__ import annotations
 
@@ -16,12 +17,21 @@ from repro.kernels.paged_attention import paged_attention as _paged
 from repro.kernels.paged_attention import paged_attention_mq as _paged_mq
 from repro.kernels.tuned_matmul import tuned_matmul
 
-ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-INTERPRET = not ON_TPU
+
+def _interpret() -> bool:
+    """Interpret mode on the CPU backend, compiled kernels on TPU.  Any
+    other backend raises: a kernel must never fall back to the interpreter
+    on a device it was not written for."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"no Pallas kernel path for backend {backend!r}: kernels compile "
+            f"on 'tpu' and run in interpret mode only on 'cpu'")
+    return backend == "cpu"
 
 
 def matmul(x, y, *, bm=128, bn=128, bk=128):
-    return tuned_matmul(x, y, bm=bm, bn=bn, bk=bk, interpret=INTERPRET)
+    return tuned_matmul(x, y, bm=bm, bn=bn, bk=bk, interpret=_interpret())
 
 
 def attention(q, k, v, *, causal=True, window=0, block_q=128, block_k=128):
@@ -29,7 +39,7 @@ def attention(q, k, v, *, causal=True, window=0, block_q=128, block_k=128):
     B, S, H, D = q.shape
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, t.shape[1], D)
     out = _flash(fold(q), fold(k), fold(v), causal=causal, window=window,
-                 bq=block_q, bk=block_k, interpret=INTERPRET)
+                 bq=block_q, bk=block_k, interpret=_interpret())
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
 
 
@@ -37,7 +47,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     block_k=0):
     """Paged decode attention, already in kernel layout (B, KVH, G, HD)."""
     return _paged(q, k_pages, v_pages, block_tables, lengths,
-                  block_k=block_k, interpret=INTERPRET)
+                  block_k=block_k, interpret=_interpret())
 
 
 def paged_attention_mq(q, k_pages, v_pages, block_tables, lengths, *,
@@ -45,7 +55,7 @@ def paged_attention_mq(q, k_pages, v_pages, block_tables, lengths, *,
     """Multi-query paged decode attention (speculative verify), kernel
     layout q: (B, S, KVH, G, HD); query s sees lengths + s positions."""
     return _paged_mq(q, k_pages, v_pages, block_tables, lengths,
-                     block_k=block_k, interpret=INTERPRET)
+                     block_k=block_k, interpret=_interpret())
 
 
 def wkv(r, k, v, w, u, s0, *, bt=256, mode="fused_recurrent"):
@@ -59,7 +69,7 @@ def wkv(r, k, v, w, u, s0, *, bt=256, mode="fused_recurrent"):
         bt = min(bt, 64)
     tr = lambda t: jnp.moveaxis(t, 1, 2).astype(jnp.float32)
     out, s = kern(tr(r), tr(k), tr(v), tr(w), u.astype(jnp.float32),
-                  s0.astype(jnp.float32), bt=bt, interpret=INTERPRET)
+                  s0.astype(jnp.float32), bt=bt, interpret=_interpret())
     return jnp.moveaxis(out, 1, 2), s
 
 
@@ -73,5 +83,5 @@ def ssd(x, b, c, dt, a, s0, *, bt=256, mode="fused_recurrent"):
     dtk = jnp.moveaxis(dt, 1, 2).astype(jnp.float32)
     y, s = kern(xk, b.astype(jnp.float32), c.astype(jnp.float32),
                 dtk, a.astype(jnp.float32), s0.astype(jnp.float32),
-                bt=bt, interpret=INTERPRET)
+                bt=bt, interpret=_interpret())
     return jnp.moveaxis(y, 1, 2), s

@@ -31,10 +31,10 @@ def _largest_collectives(hlo_text: str, n: int = 12):
     for comp, lines in hc.computations.items():
         symbols = hc._symbols(comp)
         for line in lines:
-            m = counters_mod._INSTR_RE.match(line)
-            if not m:
+            parsed = counters_mod._parse_instr(line)
+            if not parsed:
                 continue
-            name, out_type, opcode, rest = m.groups()
+            name, out_type, opcode, rest = parsed
             base = opcode.replace("-start", "")
             if base not in counters_mod.COLLECTIVES:
                 continue

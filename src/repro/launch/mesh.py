@@ -2,30 +2,15 @@
 this module never touches jax device state)."""
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-
-def _axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` kwarg for jax.make_mesh, when this jax supports it.
-
-    ``jax.sharding.AxisType`` (and the matching ``axis_types`` parameter on
-    ``jax.make_mesh``) only exist on newer jax; on 0.4.x the default mesh
-    behaviour is already Auto, so omitting the kwarg is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    if "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -42,4 +27,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     model = max(min(model, n), 1)
     data = max(min(data, n // model), 1)
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_types_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)

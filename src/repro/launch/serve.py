@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_config
+from repro.core.policy import RegionPlan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_mod
 from repro.serve.engine import Engine, ServeConfig
 from repro.serve.scheduler import Request, summarize
@@ -86,6 +88,9 @@ def run_static(engine: Engine, reqs: list[Request], slots: int) -> dict:
 
 
 def main(argv=None):
+    """Serve one trace from the CLI arguments and print the report.
+    Returns the serve result (``Engine.serve``'s dict, or the static
+    baseline's) with the engine itself under ``"engine"``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -201,6 +206,11 @@ def main(argv=None):
                     help="cache length (default: prompt+gen headroom)")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--eos-id", type=int, default=-1)
+    ap.add_argument("--plan", default="",
+                    help="RegionPlan json to serve under (e.g. a tuned "
+                         "candidate's knobs, such as attn_impl='paged' for "
+                         "the Pallas paged-attention kernel); default: the "
+                         "null plan")
     ap.add_argument("--dtree", default="",
                     help="DecisionTree json from the autotuner corpus")
     ap.add_argument("--online-retrain", action="store_true",
@@ -270,6 +280,7 @@ def main(argv=None):
                          "health transitions")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -282,7 +293,9 @@ def main(argv=None):
     if args.dtree:
         from repro.core.dtree import DecisionTree
         dtree = DecisionTree.from_json(open(args.dtree).read())
-    engine = Engine(model, params, serve_cfg=ServeConfig(
+    plan = (RegionPlan.from_json(open(args.plan).read()) if args.plan
+            else None)
+    engine = Engine(model, params, plan=plan, serve_cfg=ServeConfig(
         max_len=max_len, temperature=args.temperature, seed=args.seed,
         max_slots=args.slots, eos_id=args.eos_id,
         prefill_bucket=args.prefill_bucket, paged=args.paged,
@@ -469,6 +482,7 @@ def main(argv=None):
         with open(args.tree_out, "w") as f:
             f.write(engine.dtree.to_json())
         print(f"[autotune] dtree -> {args.tree_out}")
+    res["engine"] = engine
     return res
 
 

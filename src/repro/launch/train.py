@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.configs.registry import get_config
 from repro.core.policy import RegionPlan, default_plan, null_plan
 from repro.data.pipeline import DataConfig, Prefetcher, batch_at, iterate
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as model_mod
 from repro.optim import adamw
@@ -48,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--fail-at-step", type=int, default=0,
                     help="simulate a node failure (tests)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

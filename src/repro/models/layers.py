@@ -24,6 +24,11 @@ from repro.core.regions import region
 # ---------------------------------------------------------------------------
 
 
+# logical axes that stack independent matrices (layer-stacked blocks, MoE
+# experts); they never count toward a weight's fan-in
+STACK_AXES = ("layers", "experts")
+
+
 @dataclasses.dataclass(frozen=True)
 class Spec:
     shape: tuple
@@ -36,7 +41,10 @@ class Spec:
             return jnp.zeros(self.shape, dtype)
         if self.init == "ones":
             return jnp.ones(self.shape, dtype)
-        fan_in = self.shape[0] if self.shape else 1
+        # fan-in is the first axis that is not a stacking axis: a stacked
+        # (n_layers, d_in, d_out) leaf holds n_layers independent matrices
+        fan_in = next((n for n, a in zip(self.shape, self.axes)
+                       if a not in STACK_AXES), 1)
         std = self.scale / math.sqrt(max(fan_in, 1))
         if self.init == "small":
             std = 0.02 * self.scale

@@ -286,6 +286,47 @@ def draft_ngram(history: np.ndarray, depth: int, *, max_ngram: int = 3,
     return out
 
 
+def sample_pool(logits, active, key, temp):
+    """Pool-step sampling via the shared :func:`sample_rows`, with the
+    inactive-slot mask: freed (or mid-prefill) slots decode the null
+    page, so their logits are garbage and may be non-finite — zero
+    them before the sampler so NaNs never propagate into
+    categorical(), and pin their sampled token to 0 so downstream
+    state is occupancy-independent."""
+    logits = jnp.where(active[:, None], logits, 0.0)
+    return jnp.where(active, sample_rows(logits, key, temp), 0)
+
+
+def paged_step_fn(model: Model, plan: RegionPlan, temperature: float,
+                  mesh=None):
+    """The paged pool's decode(+verify)+sample step, before jit:
+    ``step(params, pages, tokens (B,S), block_tables, lengths, active,
+    key) -> (tokens (B,S), finite (B,), pages)``.  ``plan`` is the
+    already-resolved serve plan; a ``mesh`` (tp > 1) replicates the
+    vocab-sharded logits before sampling.  :meth:`Engine._build_paged_step`
+    compiles it for the live pool, and a compile check can lower it on
+    abstract shapes for a device that is not attached."""
+
+    def step(params, pages, tokens, block_tables, lengths, active, key):
+        logits, pages = model.paged_decode(
+            params, pages, tokens, block_tables, lengths, plan)
+        B, S_, V = logits.shape
+        flat = logits.astype(jnp.float32).reshape(B * S_, V)
+        if mesh is not None:
+            # THE collective boundary: replicate the vocab-sharded
+            # logits (one all-gather) before sampling, so everything
+            # downstream is shard-independent
+            flat = jax.lax.with_sharding_constraint(
+                flat, NamedSharding(mesh, P()))
+        act = jnp.repeat(active, S_)
+        finite = (jnp.isfinite(flat).all(axis=-1).reshape(B, S_)
+                  .all(axis=-1) | ~active)
+        return (sample_pool(flat, act, key, temperature).reshape(B, S_),
+                finite, pages)
+
+    return step
+
+
 class Engine:
     # class-level defaults so resolution helpers (tp_for/spec_depth_for)
     # stay callable on partially-constructed engine shells (tests stub
@@ -797,16 +838,6 @@ class Engine:
             self._tap_region = (attn[0] if attn
                                 else (tops[0][0] if tops else None))
 
-    def _sample_pool(self, logits, active, key, temp):
-        """Pool-step sampling via the shared :func:`sample_rows`, with the
-        inactive-slot mask: freed (or mid-prefill) slots decode the null
-        page, so their logits are garbage and may be non-finite — zero
-        them before the sampler so NaNs never propagate into
-        categorical(), and pin their sampled token to 0 so downstream
-        state is occupancy-independent."""
-        logits = jnp.where(active[:, None], logits, 0.0)
-        return jnp.where(active, sample_rows(logits, key, temp), 0)
-
     def _build_pool_step(self, plan: RegionPlan):
         """AOT-compile one decode(+verify)+sample step over the whole slot
         pool: the model's single-request ``decode_step`` vmapped over the
@@ -832,7 +863,6 @@ class Engine:
         Returns (compiled, D, tp=1); the compiled step returns
         ``(tokens (B,S), finite (B,), pool)``."""
         model, temp = self.model, self.cfg.temperature
-        sample = self._sample_pool
         depth = self.spec_depth_for(plan)
         S = depth + 1
         splan = self._plan_with_scan_mode(plan, self.scan_mode_for(plan))
@@ -848,7 +878,8 @@ class Engine:
             act = jnp.repeat(active, S_)
             finite = (jnp.isfinite(flat).all(axis=-1).reshape(B, S_)
                       .all(axis=-1) | ~active)
-            return sample(flat, act, key, temp).reshape(B, S_), finite, pool
+            return (sample_pool(flat, act, key, temp).reshape(B, S_),
+                    finite, pool)
 
         B = self._pool.n_slots
         return jax.jit(step, donate_argnums=(1,)).lower(
@@ -884,30 +915,13 @@ class Engine:
         retries it (see ``_serve_paged``).  Returns (compiled, D, tp);
         the compiled step returns ``(tokens (B,S), finite (B,), pages)``.
         """
-        model, temp = self.model, self.cfg.temperature
-        sample = self._sample_pool
         depth = self.spec_depth_for(plan)
         S = depth + 1
         tp = self.tp_for(plan)
         self._apply_tp(tp)          # lowering captures the live placement
         splan = self._serve_plan(plan, tp)
         mesh = splan.mesh if tp > 1 else None
-
-        def step(params, pages, tokens, block_tables, lengths, active, key):
-            logits, pages = model.paged_decode(
-                params, pages, tokens, block_tables, lengths, splan)
-            B, S_, V = logits.shape
-            flat = logits.astype(jnp.float32).reshape(B * S_, V)
-            if mesh is not None:
-                # THE collective boundary: replicate the vocab-sharded
-                # logits (one all-gather) before sampling, so everything
-                # downstream is shard-independent
-                flat = jax.lax.with_sharding_constraint(
-                    flat, NamedSharding(mesh, P()))
-            act = jnp.repeat(active, S_)
-            finite = (jnp.isfinite(flat).all(axis=-1).reshape(B, S_)
-                      .all(axis=-1) | ~active)
-            return sample(flat, act, key, temp).reshape(B, S_), finite, pages
+        step = paged_step_fn(self.model, splan, self.cfg.temperature, mesh)
 
         pool = self._pool
         B, MP = pool.n_slots, pool.max_pages_per_slot

@@ -7,12 +7,8 @@ import math
 import numpy as np
 import pytest
 
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:  # fall back to the deterministic local shim
-    from _hypothesis_compat import given, settings
-    from _hypothesis_compat import strategies as st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.autotune.corpus import Corpus, CorpusEntry
 from repro.autotune.explorer import EpsilonGreedyExplorer

@@ -103,3 +103,47 @@ def test_region_discovery(key):
 
     regs = discover_regions(f, jnp.ones((4,)))
     assert regs == {"a", "a/b"}
+
+
+def test_tpu_layouts_parse():
+    """TPU HLO: tiled layouts with memory spaces inside the braces, tuple
+    types that nest them, matmuls lowered to convolutions (one of them a
+    windowed convolution over a padded size-1 spatial dim) and a loop bound
+    whose constant carries a layout."""
+    hlo = """
+HloModule tpu_layouts
+
+%body (p.1: (s32[], bf16[4,2048], bf16[2048,2048])) -> (s32[], bf16[4,2048], bf16[2048,2048]) {
+  %p.1 = (s32[]{:T(128)}, bf16[4,2048]{1,0:T(4,128)(2,1)}, bf16[2048,2048]{1,0:T(8,128)(2,1)}) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%p.1), index=0
+  %x = bf16[4,2048]{1,0:T(4,128)(2,1)} get-tuple-element(%p.1), index=1
+  %w = bf16[2048,2048]{1,0:T(8,128)(2,1)} get-tuple-element(%p.1), index=2
+  %one = s32[]{:T(128)} constant(1)
+  %i2 = s32[]{:T(128)} add(%i, %one)
+  %y = bf16[4,2048]{1,0:T(4,128)(2,1)S(1)} convolution(%x, %w), dim_labels=bf_io->bf, metadata={op_name="jit(f)/R.mm/dot_general"}
+  ROOT %t = (s32[]{:T(128)}, bf16[4,2048]{1,0:T(4,128)(2,1)}, bf16[2048,2048]{1,0:T(8,128)(2,1)}) tuple(%i2, %y, %w)
+}
+
+%cond (p.2: (s32[], bf16[4,2048], bf16[2048,2048])) -> pred[] {
+  %p.2 = (s32[]{:T(128)}, bf16[4,2048]{1,0:T(4,128)(2,1)}, bf16[2048,2048]{1,0:T(8,128)(2,1)}) parameter(0)
+  %j = s32[]{:T(128)} get-tuple-element(%p.2), index=0
+  %n = s32[]{:T(128)} constant(24)
+  ROOT %lt = pred[]{:T(512)} compare(%j, %n), direction=LT
+}
+
+ENTRY %main (x.1: bf16[4,2048], w.1: bf16[2048,2048], wq: bf16[2048,32,64]) -> bf16[4,32,64] {
+  %x.1 = bf16[4,2048]{1,0:T(4,128)(2,1)} parameter(0)
+  %w.1 = bf16[2048,2048]{1,0:T(8,128)(2,1)} parameter(1)
+  %wq = bf16[2048,32,64]{2,1,0:T(8,128)(2,1)} parameter(2)
+  %zero = s32[]{:T(128)} constant(0)
+  %init = (s32[]{:T(128)}, bf16[4,2048]{1,0:T(4,128)(2,1)}, bf16[2048,2048]{1,0:T(8,128)(2,1)}) tuple(%zero, %x.1, %w.1)
+  %loop = (s32[]{:T(128)}, bf16[4,2048]{1,0:T(4,128)(2,1)}, bf16[2048,2048]{1,0:T(8,128)(2,1)}) while(%init), condition=%cond, body=%body
+  %h = bf16[4,2048]{1,0:T(4,128)(2,1)} get-tuple-element(%loop), index=1
+  %xr = bf16[4,2048,1]{1,0,2:T(4,128)(2,1)} reshape(%h)
+  ROOT %q = bf16[4,32,64]{2,0,1:T(4,128)(2,1)S(1)} convolution(%xr, %wq), window={size=32 pad=31_31 rhs_reversal=1}, dim_labels=bf0_i0o->b0f, metadata={op_name="jit(f)/R.proj/dot_general"}
+}
+"""
+    rc = counters.collect_from_text(hlo)
+    assert rc.regions["mm"].flops == 24 * 2 * 4 * 2048 * 2048
+    # 31 of the 32 window taps of every output read padding
+    assert rc.regions["proj"].flops == 2 * 4 * 32 * 64 * 2048

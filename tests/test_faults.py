@@ -11,12 +11,8 @@ import os
 import numpy as np
 import pytest
 
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:  # fall back to the deterministic local shim
-    from _hypothesis_compat import given, settings
-    from _hypothesis_compat import strategies as st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 import jax
 import jax.numpy as jnp
@@ -431,7 +427,10 @@ def test_engine_deadline_and_queue_shed(served_model):
     eng = Engine(model, params, serve_cfg=ServeConfig(
         **_CHAOS_COMMON, max_queue=3))
     gens = (6, 5, 6, 5, 6, 5, 6, 5, 6)
-    reqs = _trace(cfg.vocab_size, n=9, gens=gens, deadlines={3: 2e-4})
+    # request 3 must expire at the first shed, before the backlog cap
+    # counts it; a 1 ns deadline has passed by then on any host, where
+    # 0.2 ms raced the admission pass that precedes the shed
+    reqs = _trace(cfg.vocab_size, n=9, gens=gens, deadlines={3: 1e-9})
     res = eng.serve(reqs)
     by_state = {r.rid: r.state for r in reqs}
     assert by_state[3] is RequestState.EXPIRED

@@ -7,12 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:  # fall back to the deterministic local shim
-    from _hypothesis_compat import given, settings
-    from _hypothesis_compat import strategies as st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.configs.registry import get_config
 from repro.core.policy import RegionConfig, RegionPlan, null_plan

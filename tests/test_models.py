@@ -1,6 +1,9 @@
 """Per-arch smoke tests: reduced config, one forward/train step on CPU,
 output shapes + finiteness; prefill+decode consistency against the full
 forward (the serving-correctness invariant)."""
+import dataclasses
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +11,7 @@ import pytest
 
 from conftest import tiny_batch
 from repro.configs.registry import ARCH_IDS, get_config
+from repro.models.layers import STACK_AXES
 from repro.models.model import build, count_params
 from repro.optim import adamw
 from repro.train import trainer
@@ -119,3 +123,25 @@ def test_swa_ring_cache_matches_full(key):
             np.asarray(logits_dec[:, 0], np.float32),
             np.asarray(logits_full[:, S + t], np.float32),
             rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("arch,path,fan_in", [
+    ("stablelm-1.6b", ("blocks", "mlp", "up"), 2048),
+    ("stablelm-1.6b", ("blocks", "mlp", "down"), 5632),
+    ("stablelm-1.6b", ("blocks", "attn", "wq"), 2048),
+    ("granite-moe-1b-a400m", ("blocks", "mlp", "up"), 1024),
+])
+def test_stacked_init_std_is_inverse_sqrt_fan_in(arch, path, fan_in, key):
+    """A full-width layer-stacked (and expert-stacked) leaf holds
+    independent matrices: its init std is 1/sqrt(d_in), not 1/sqrt of the
+    layer or expert count.  The stacking axes are cut to 2 to keep the
+    sample small; the std does not depend on them."""
+    spec = build(get_config(arch)).spec()
+    for k in path:
+        spec = spec[k]
+    assert spec.axes[0] == "layers"
+    cut = tuple(2 if a in STACK_AXES else n
+                for n, a in zip(spec.shape, spec.axes))
+    w = np.asarray(dataclasses.replace(spec, shape=cut).materialise(
+        key, jnp.float32))
+    assert abs(w.std() * math.sqrt(fan_in) - 1.0) < 0.02

@@ -5,12 +5,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:  # fall back to the deterministic local shim
-    from _hypothesis_compat import given, settings
-    from _hypothesis_compat import strategies as st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.core.policy import (DEFAULT_RULES, RegionConfig, RegionPlan,
                                default_plan, legal_spec)

@@ -5,12 +5,8 @@ of cache-hit serving vs a cold pool."""
 import numpy as np
 import pytest
 
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:  # fall back to the deterministic local shim
-    from _hypothesis_compat import given, settings
-    from _hypothesis_compat import strategies as st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 import jax
 import jax.numpy as jnp

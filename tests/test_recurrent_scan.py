@@ -23,12 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:  # fall back to the deterministic local shim
-    from _hypothesis_compat import given, settings
-    from _hypothesis_compat import strategies as st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.models.mamba2 import ssd_chunked, ssd_scan
 from repro.models.rwkv6 import wkv_chunked, wkv_scan
