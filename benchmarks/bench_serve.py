@@ -80,9 +80,9 @@ just this section.
 
 The **observability rows** replay the mixed-length trace on two engines,
 telemetry off (the one-``is not None`` disabled path) vs fully on at
-``debug`` level (span tracer + metrics ring + latency sketches +
-per-step events), asserting bit-identical greedy tokens, loadable
-Chrome-trace / parseable Prometheus exports, and telemetry-on tok/s
+``debug`` level (span tracer + latency sketches + per-step events),
+asserting bit-identical greedy tokens, loadable Chrome-trace /
+parseable Prometheus exports, and telemetry-on tok/s
 >= 0.97x off.  Gated by CI's ``obs-smoke`` job via
 ``ratios.telemetry_on_vs_off_tok_s``; ``--obs-only`` runs just this
 section.
@@ -723,8 +723,8 @@ def _obs_section(model, params, vocab: int, reps: int = 3) -> tuple[list, dict]:
     """Telemetry overhead: two engines replay the identical mixed-length
     trace, one with the telemetry subsystem off (the ``is not None``
     disabled path) and one fully on at ``debug`` level (span tracer +
-    metrics ring + latency sketches + per-step structured events — the
-    worst case).  Gates (CI's ``obs-smoke`` job, via
+    latency sketches + per-step structured events — the worst case).
+    Gates (CI's ``obs-smoke`` job, via
     ``ratios.telemetry_on_vs_off_tok_s``): greedy tokens bit-identical,
     telemetry-on tok/s >= ``OBS_GATE`` x off, and the exporters actually
     produce a loadable Chrome trace + parseable Prometheus text.
@@ -766,8 +766,8 @@ def _obs_section(model, params, vocab: int, reps: int = 3) -> tuple[list, dict]:
             float(val)                     # every sample value parses
     assert "repro_serve_step_latency_seconds" in prom
     tm = res_on["telemetry"]
-    assert tm["ring"]["steps"] == res_on["steps"], (
-        "metrics ring missed decode steps")
+    assert tm["step_latency_s"]["count"] == res_on["steps"], (
+        "step-latency sketch missed decode steps")
 
     rows = [
         (f"serve_obs_off,{1e6 / max(s_off['tok_per_s'], 1e-9):.1f},"
@@ -776,8 +776,7 @@ def _obs_section(model, params, vocab: int, reps: int = 3) -> tuple[list, dict]:
          f"{s_on['tok_per_s']:.1f}"),
         f"serve_obs_on_vs_off,{ratio:.2f},gate>={OBS_GATE}",
         (f"serve_obs_spans,{tm['spans']},"
-         f"events={tm['events']}_ring={tm['ring']['kept']}"
-         f"of{tm['ring']['steps']}"),
+         f"events={tm['events']}_steps={tm['step_latency_s']['count']}"),
     ]
     sec = {
         "level": OBS_LEVEL, "gate": OBS_GATE,
@@ -789,7 +788,6 @@ def _obs_section(model, params, vocab: int, reps: int = 3) -> tuple[list, dict]:
                "spans": tm["spans"],
                "spans_dropped": tm["spans_dropped"],
                "events": tm["events"],
-               "ring": tm["ring"],
                "step_latency_s": tm["step_latency_s"],
                "queue_delay_s": tm["queue_delay_s"],
                "ttft_s": tm["ttft_s"]},

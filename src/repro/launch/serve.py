@@ -259,7 +259,7 @@ def main(argv=None):
                          "streams; same seed+rate = same fault schedule)")
     ap.add_argument("--telemetry", action="store_true",
                     help="enable the serve telemetry subsystem (span "
-                         "tracer, step metrics ring, latency sketches) "
+                         "tracer, latency sketches, event log) "
                          "without any file exports; implied by "
                          "--trace-out/--metrics-out/--log-out.  Greedy "
                          "output stays bit-identical with telemetry on")
@@ -461,8 +461,7 @@ def main(argv=None):
               f"spans={tm.get('spans', 0)} "
               f"(dropped={tm.get('spans_dropped', 0)}) "
               f"events={tm.get('events', 0)} "
-              f"ring={tm.get('ring', {}).get('kept', 0)}/"
-              f"{tm.get('ring', {}).get('steps', 0)} steps  "
+              f"steps={lat.get('count', 0)}  "
               f"step p50 {lat.get('p50', 0.0)*1e3:.1f} ms "
               f"p99 {lat.get('p99', 0.0)*1e3:.1f} ms  "
               f"queue p99 {qd.get('p99', 0.0)*1e3:.1f} ms")

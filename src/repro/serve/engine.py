@@ -92,6 +92,7 @@ with exploration off, greedy output stays bit-identical.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 from typing import Any, Optional, Sequence
 
@@ -110,6 +111,7 @@ from repro.serve.faults import FaultInjector
 from repro.serve.health import HealthMonitor, HealthPolicy
 from repro.serve.scheduler import (Request, RequestState, Scheduler,
                                    summarize)
+from repro.serve.telemetry import GcSpans, span
 
 
 @dataclasses.dataclass
@@ -212,10 +214,10 @@ class ServeConfig:
     chaos_seed: int = 0         # FaultInjector stream seed
     chaos_sites: tuple = ()     # subset of faults.FAULT_SITES (empty =
                                 # all sites)
-    # -- telemetry (serve/telemetry.py: spans + metrics ring + exporters) ----
+    # -- telemetry (serve/telemetry.py: request spans + sketches + exports) -
     telemetry: bool = False     # build the Telemetry subsystem (request
-                                # span tracing, bounded step-metrics
-                                # ring, latency sketches).  Off = the
+                                # span tracing, latency sketches,
+                                # event log).  Off = the
                                 # attribute stays None and every hot
                                 # path pays one `is not None` check —
                                 # the FaultInjector contract.  Any of
@@ -400,10 +402,11 @@ class Engine:
         self._fallback = None                       # (step, depth, tp) to
                                                     # restore on recovery
 
-        # -- telemetry (spans + metrics ring + exporters) --------------------
+        # -- telemetry (request spans + sketches + exporters) ----------------
         # same zero-overhead contract as the injector: None unless asked
         # for, and every hot-path touch is one `is not None` check
         self.telemetry = None
+        self._gc_spans = GcSpans()      # python.gc spans while profiled
         if (self.cfg.telemetry or self.cfg.trace_out or self.cfg.metrics_out
                 or self.cfg.log_out):
             from repro.serve.telemetry import Telemetry
@@ -1320,7 +1323,20 @@ class Engine:
         (structurally infeasible requests — a programmer error, checked
         before any state exists) and engine-internal errors, which abort
         the trace after releasing every resident's pages.
+
+        Under a profiler session the call is a ``serve.run`` span holding
+        the loop's phase spans (docs/observability.md), and each garbage
+        collection during it a ``python.gc`` span.
         """
+        gc.callbacks.append(self._gc_spans)
+        try:
+            with span("serve.run"):
+                return self._serve(requests)
+        finally:
+            gc.callbacks.remove(self._gc_spans)
+
+    def _serve(self, requests: Sequence[Request]) -> dict:
+        """:meth:`serve`'s body."""
         self._ensure_pool()
         for r in requests:
             self._validate(r)
@@ -1719,7 +1735,7 @@ class Engine:
             self.health.note_step(dt_step, n_slot_faults=len(faulted))
             self._tap_step(n_act, sum(consumed.values()), dt_step)
             if tel is not None:
-                tel.on_step(steps, t_step0 - t0, dt_step,
+                tel.on_step(steps, dt_step,
                             sum(consumed.values()), n_act, pool.n_free,
                             len(faulted),
                             self._bucket_class.get(load_bucket(n_act), ""))
@@ -1789,7 +1805,6 @@ class Engine:
         active = np.zeros((B,), bool)
         prefills: list[Request] = []        # admitted, mid-prefill (FIFO)
         key = jax.random.PRNGKey(self.cfg.seed)
-        t0 = time.perf_counter()
         now = lambda: time.perf_counter() - t0  # noqa: E731
         steps = 0
         committed_total = 0                 # tokens committed by decode steps
@@ -1833,11 +1848,13 @@ class Engine:
             bt_dev["dirty"] = True
             sched.fail(req, now(), reason)
 
-        def admit_ready(t):
+        def admit_ready(t) -> int:
+            """Admit what is due and fits; returns how many were."""
+            n = 0
             while True:
                 req = sched.peek_ready(t)
                 if req is None:
-                    return
+                    return n
                 # SHEDDING rung of the degradation ladder: stop taking on
                 # fresh work while faults are this frequent — preempted
                 # residents still re-enter (their progress is paid for).
@@ -1847,7 +1864,7 @@ class Engine:
                 if (self.health.shedding
                         and req.state is RequestState.WAITING
                         and (sched.active or sched.prefilling)):
-                    return
+                    return n
                 # duplicate-arrival dedup: a fresh request whose prompt
                 # matches one still mid-prefill is HELD (head-of-line, FIFO
                 # preserved) until the twin publishes its prefix pages —
@@ -1865,7 +1882,7 @@ class Engine:
                            and np.array_equal(r.prompt, req.prompt)
                            for r in sched.prefilling.values()):
                         pool.dedup_holds += 1
-                        return
+                        return n
                 # a preempted request re-enters as recompute-prefill over
                 # prompt + generated-so-far; its worst case is unchanged
                 # (every recomputed token replaces a remaining new one)
@@ -1892,9 +1909,10 @@ class Engine:
                 fresh = req.state is RequestState.WAITING
                 slot = gov.admit(hist.size, total, shared_pages=shared)
                 if slot is None:            # head-of-line waits for memory
-                    return
+                    return n
                 sched.pop_ready(t)
                 sched.bind_prefill(req, slot, now())
+                n += 1
                 # queue-delay tap: admission wait of fresh arrivals (a
                 # PREEMPTED re-entry's wait is requeue_wait_s, tracked
                 # separately) feeds the Counters channel and, when on,
@@ -1920,85 +1938,107 @@ class Engine:
                 else:
                     prefills.append(req)
 
-        try:
-          while not sched.done():
-            admit_ready(now())
-            # load shedding right after admission: whatever is STILL
-            # arrived-but-waiting is past-deadline fodder and counts
-            # against the queue bound — explicit EXPIRED/REJECTED
-            # outcomes instead of unbounded queueing
-            sched.shed_waiting(now(), self.cfg.max_queue,
-                               self.cfg.deadline_s)
+        def run_pass(sp) -> bool:
+            """One pass of the loop: admission, at most
+            ``prefill_chunks_per_step`` prefill chunks, then one pool
+            decode step.  False when nothing is left to serve.  Each
+            phase is a profiler span; ``sp`` is the pass's own (None
+            while no profiler records)."""
+            nonlocal key, steps, committed_total, slot_steps, max_depth
+            nonlocal prev_stall
+            with span("serve.admit") as ph:
+                n_admitted = admit_ready(now())
+                # load shedding right after admission: whatever is STILL
+                # arrived-but-waiting is past-deadline fodder and counts
+                # against the queue bound — explicit EXPIRED/REJECTED
+                # outcomes instead of unbounded queueing
+                sched.shed_waiting(now(), self.cfg.max_queue,
+                                   self.cfg.deadline_s)
+                if ph is not None:
+                    ph.set_metadata(admitted=n_admitted,
+                                    waiting=sched.n_waiting(now()))
 
             # interleaved chunked prefill: a bounded budget per loop pass
             budget = max(self.cfg.prefill_chunks_per_step, 1)
+            n_chunks = 0
             while budget > 0 and prefills:
-                req = prefills[0]
-                slot = req.slot
-                feed = req.token_history()[:-1]
-                # MoE capacity groups depend on the token-group length, so
-                # splitting a prompt would route (and drop) differently
-                # than whole-prompt prefill — keep MoE prompts one chunk
-                if self.model.cfg.n_experts:
-                    C = feed.size
-                else:
-                    C = self.cfg.prefill_chunk or feed.size
-                chunk = feed[req.prefill_pos:req.prefill_pos + C]
-                true_c = chunk.size
-                if true_c < C:
-                    chunk = np.pad(chunk, (0, C - true_c))
-                tc0 = now() if tel is not None else 0.0
-                pool.pages = self._chunk_fn()(
-                    self._step_params, pool.pages,
-                    jnp.asarray(chunk[None]),
-                    jnp.asarray(pool.block_tables[slot]),
-                    jnp.asarray(req.prefill_pos, jnp.int32))
-                if tel is not None:
-                    tel.tracer.add(req.rid, "PREFILL_CHUNK", tc0, now(),
-                                   tokens=int(true_c))
-                budget -= 1
-                if (self.faults is not None
-                        and self.faults.fire("prefill.nan")):
-                    # the chunk's K/V is suspect: advance nothing, so the
-                    # retry deterministically rewrites the same rows with
-                    # the same values.  Rotate to the back of the prefill
-                    # line so a repeat offender never head-of-line blocks
-                    # healthy prompts.
-                    req.retries += 1
-                    req.fail_streak += 1
-                    if req.fail_streak > self.health.policy.max_retries:
-                        prefills.pop(0)
-                        fail_request(slot, req,
-                                     "prefill fault past max_retries")
+                with span("serve.chunk_dispatch") as ph:
+                    req = prefills[0]
+                    slot = req.slot
+                    feed = req.token_history()[:-1]
+                    # MoE capacity groups depend on the token-group
+                    # length, so splitting a prompt would route (and
+                    # drop) differently than whole-prompt prefill — keep
+                    # MoE prompts one chunk
+                    if self.model.cfg.n_experts:
+                        C = feed.size
                     else:
-                        prefills.append(prefills.pop(0))
-                    continue
-                req.fail_streak = 0
-                pool.advance(slot, true_c)
-                req.prefill_pos += true_c
-                if req.prefill_pos >= feed.size:
-                    pending[slot] = int(req.token_history()[-1])
-                    # the prompt's full pages are now written: publish them
-                    # so concurrent same-prefix arrivals hit immediately
-                    pool.register_prefix(slot, req.token_history())
-                    sched.start_decode(req, now())
-                    active[slot] = True
-                    bt_dev["dirty"] = True
-                    prefills.pop(0)
+                        C = self.cfg.prefill_chunk or feed.size
+                    chunk = feed[req.prefill_pos:req.prefill_pos + C]
+                    true_c = chunk.size
+                    if ph is not None:
+                        ph.set_metadata(rid=req.rid, tokens=int(true_c),
+                                        base=int(req.prefill_pos))
+                    if true_c < C:
+                        chunk = np.pad(chunk, (0, C - true_c))
+                    tc0 = now() if tel is not None else 0.0
+                    pool.pages = self._chunk_fn()(
+                        self._step_params, pool.pages,
+                        jnp.asarray(chunk[None]),
+                        jnp.asarray(pool.block_tables[slot]),
+                        jnp.asarray(req.prefill_pos, jnp.int32))
+                    if tel is not None:
+                        tel.tracer.add(req.rid, "PREFILL_CHUNK", tc0, now(),
+                                       tokens=int(true_c))
+                    budget -= 1
+                    n_chunks += 1
+                    if (self.faults is not None
+                            and self.faults.fire("prefill.nan")):
+                        # the chunk's K/V is suspect: advance nothing, so
+                        # the retry deterministically rewrites the same
+                        # rows with the same values.  Rotate to the back
+                        # of the prefill line so a repeat offender never
+                        # head-of-line blocks healthy prompts.
+                        req.retries += 1
+                        req.fail_streak += 1
+                        if req.fail_streak > self.health.policy.max_retries:
+                            prefills.pop(0)
+                            fail_request(slot, req,
+                                         "prefill fault past max_retries")
+                        else:
+                            prefills.append(prefills.pop(0))
+                        continue
+                    req.fail_streak = 0
+                    pool.advance(slot, true_c)
+                    req.prefill_pos += true_c
+                    if req.prefill_pos >= feed.size:
+                        pending[slot] = int(req.token_history()[-1])
+                        # the prompt's full pages are now written: publish
+                        # them so concurrent same-prefix arrivals hit
+                        # immediately
+                        pool.register_prefix(slot, req.token_history())
+                        sched.start_decode(req, now())
+                        active[slot] = True
+                        bt_dev["dirty"] = True
+                        prefills.pop(0)
+            if sp is not None:
+                sp.set_metadata(chunks=n_chunks)
 
             if not sched.active:
                 if prefills:
-                    continue                # keep prefilling
+                    return True             # keep prefilling
                 nxt = sched.next_arrival()
                 if nxt is None:
-                    break
+                    return False
                 dt = nxt - now()
                 if dt > 0:
-                    time.sleep(min(dt, 0.05))
-                continue
+                    with span("serve.idle"):
+                        time.sleep(min(dt, 0.05))
+                return True
 
             n_act = len(sched.active)
-            self._maybe_replan(n_act)
+            with span("serve.tap"):
+                self._maybe_replan(n_act)
             t_step0 = time.perf_counter()
             D = self._spec_depth
             S = D + 1
@@ -2010,191 +2050,235 @@ class Engine:
             # oldest may evict past the preempt cap so the head of the
             # line always progresses; everyone else stalls when nothing is
             # reclaimable.
-            stalled: list[int] = []
-            grown0 = gov.grown_pages
-            cow0 = pool.cow_copies
-            order = sorted(sched.active, key=lambda s: (
-                sched.active[s].t_admit or 0.0, sched.active[s].rid))
-            for i, slot in enumerate(order):
-                if slot not in sched.active:
-                    continue                # taken as an earlier victim
-                req = sched.active[slot]
-                if req.backoff > 0:
-                    # capped-backoff retry: a recently-faulted slot sits
-                    # out (masked like a stall — nothing written, nothing
-                    # committed, pending untouched) and neither grows nor
-                    # evicts anyone while it waits
-                    req.backoff -= 1
-                    if (req.backoff == 0 and tel is not None):
-                        tel.tracer.end(req.rid, "RETRY_BACKOFF", now())
-                    stalled.append(slot)
-                    continue
-                cap = req.prompt.size - 1 + req.max_new_tokens
-                cow0_slot = pool.cow_copies if tel is not None else 0
-                # besides headroom, this step's K/V writes must land in
-                # *private* pages: cow_for_write copies any still-shared
-                # page in the write range first (copy-on-write), and a
-                # failed copy is handled exactly like a failed growth
-                while (slot in sched.active
-                       and (gov.ensure_headroom(slot, S, cap) < 1
-                            or not pool.cow_for_write(slot, S))):
-                    # only strictly-younger residents are evictable (LIFO:
-                    # a slot never discards its own K/V — stalling keeps
-                    # it — and never inverts the order by evicting an
-                    # older request); the oldest may override the preempt
-                    # cap so the head of the line always finishes
-                    victim = gov.pick_victim(
-                        sched.active, ignore_cap=(i == 0),
-                        younger_than=(req.t_admit or 0.0, req.rid))
-                    if victim is None:
+            with span("serve.headroom") as ph:
+                stalled: list[int] = []
+                grown0 = gov.grown_pages
+                cow0 = pool.cow_copies
+                preempts0 = pool.n_preempts
+                order = sorted(sched.active, key=lambda s: (
+                    sched.active[s].t_admit or 0.0, sched.active[s].rid))
+                for i, slot in enumerate(order):
+                    if slot not in sched.active:
+                        continue            # taken as an earlier victim
+                    req = sched.active[slot]
+                    if req.backoff > 0:
+                        # capped-backoff retry: a recently-faulted slot
+                        # sits out (masked like a stall — nothing written,
+                        # nothing committed, pending untouched) and
+                        # neither grows nor evicts anyone while it waits
+                        req.backoff -= 1
+                        if (req.backoff == 0 and tel is not None):
+                            tel.tracer.end(req.rid, "RETRY_BACKOFF", now())
                         stalled.append(slot)
-                        break
-                    preempt_victim(victim)
-                if tel is not None and pool.cow_copies > cow0_slot:
-                    # shared pages privatised for this slot's write range
-                    tel.tracer.instant(req.rid, "COW", now(),
-                                       copies=pool.cow_copies - cow0_slot)
-            stalled = [s for s in stalled if s in sched.active]
-            if gov.grown_pages != grown0 or pool.cow_copies != cow0:
-                # growth and CoW edit block-table rows in place — the
-                # cached device copy is stale even though pool composition
-                # is not
-                bt_dev["dirty"] = True
+                        continue
+                    cap = req.prompt.size - 1 + req.max_new_tokens
+                    cow0_slot = pool.cow_copies if tel is not None else 0
+                    # besides headroom, this step's K/V writes must land in
+                    # *private* pages: cow_for_write copies any
+                    # still-shared page in the write range first
+                    # (copy-on-write), and a failed copy is handled
+                    # exactly like a failed growth
+                    while (slot in sched.active
+                           and (gov.ensure_headroom(slot, S, cap) < 1
+                                or not pool.cow_for_write(slot, S))):
+                        # only strictly-younger residents are evictable
+                        # (LIFO: a slot never discards its own K/V —
+                        # stalling keeps it — and never inverts the order
+                        # by evicting an older request); the oldest may
+                        # override the preempt cap so the head of the
+                        # line always finishes
+                        victim = gov.pick_victim(
+                            sched.active, ignore_cap=(i == 0),
+                            younger_than=(req.t_admit or 0.0, req.rid))
+                        if victim is None:
+                            stalled.append(slot)
+                            break
+                        preempt_victim(victim)
+                    if tel is not None and pool.cow_copies > cow0_slot:
+                        # shared pages privatised for this slot's write
+                        # range
+                        tel.tracer.instant(req.rid, "COW", now(),
+                                           copies=pool.cow_copies - cow0_slot)
+                stalled = [s for s in stalled if s in sched.active]
+                if gov.grown_pages != grown0 or pool.cow_copies != cow0:
+                    # growth and CoW edit block-table rows in place — the
+                    # cached device copy is stale even though pool
+                    # composition is not
+                    bt_dev["dirty"] = True
+                if ph is not None:
+                    ph.set_metadata(grown=gov.grown_pages - grown0,
+                                    cow=pool.cow_copies - cow0,
+                                    preempted=pool.n_preempts - preempts0)
+            if sp is not None:
+                sp.set_metadata(active=n_act, stalled=len(stalled))
             if sched.active and len(stalled) == len(sched.active):
                 # every decode is out of pages and nothing is reclaimable:
                 # only resident prefills (whose pages are pre-reserved) can
                 # free the jam by finishing — keep prefilling, skip the step
                 gov.note_step(len(stalled))
-                continue
+                return True
 
-            max_depth = max(max_depth, D)
-            toks_in = np.zeros((B, S), np.int32)
-            toks_in[:, 0] = pending
-            if D:
-                for slot, req in sched.active.items():
-                    toks_in[slot, 1:] = draft_ngram(req.token_history(), D)
-            key, sub = jax.random.split(key)
-            # expose only non-stalled DECODE slots to the step (null page
-            # otherwise); a stalled slot keeps its pending token and state
-            # untouched and simply retries next step
-            stall_arr = np.zeros((B,), bool)
-            stall_arr[stalled] = True
-            if set(stalled) != prev_stall:
-                prev_stall = set(stalled)
-                bt_dev["dirty"] = True
-            eff = active & ~stall_arr
-            if bt_dev["dirty"]:
-                bt_dev["arr"] = jnp.asarray(
-                    pool.block_tables * eff[:, None])
-                bt_dev["act"] = jnp.asarray(eff)
-                bt_dev["dirty"] = False
-            out, finite, pool.pages = self._pool_step(
-                self._step_params, pool.pages, jnp.asarray(toks_in),
-                bt_dev["arr"], jnp.asarray(pool.lengths * eff),
-                bt_dev["act"], sub)
+            with span("serve.step_dispatch") as ph:
+                max_depth = max(max_depth, D)
+                toks_in = np.zeros((B, S), np.int32)
+                toks_in[:, 0] = pending
+                if D:
+                    for slot, req in sched.active.items():
+                        toks_in[slot, 1:] = draft_ngram(req.token_history(),
+                                                        D)
+                key, sub = jax.random.split(key)
+                # expose only non-stalled DECODE slots to the step (null
+                # page otherwise); a stalled slot keeps its pending token
+                # and state untouched and simply retries next step
+                stall_arr = np.zeros((B,), bool)
+                stall_arr[stalled] = True
+                if set(stalled) != prev_stall:
+                    prev_stall = set(stalled)
+                    bt_dev["dirty"] = True
+                eff = active & ~stall_arr
+                if bt_dev["dirty"]:
+                    bt_dev["arr"] = jnp.asarray(
+                        pool.block_tables * eff[:, None])
+                    bt_dev["act"] = jnp.asarray(eff)
+                    bt_dev["dirty"] = False
+                lengths = pool.lengths * eff
+                out, finite, pool.pages = self._pool_step(
+                    self._step_params, pool.pages, jnp.asarray(toks_in),
+                    bt_dev["arr"], jnp.asarray(lengths), bt_dev["act"], sub)
+                if ph is not None:
+                    ph.set_metadata(active=int(eff.sum()),
+                                    live_tokens=int(lengths.sum()))
             if (self.faults is not None
                     and self.faults.fire("step.latency")):
                 # artificial latency spike, inside the step's measured
                 # window so the watchdog (and the tap's reward) sees it
                 time.sleep(self.faults.latency_s)
             steps += 1
+            if sp is not None:
+                sp.set_metadata(step=steps)
             gov.note_step(len(stalled))
-            out_np = np.asarray(out)
-            finite_np = np.asarray(finite)
+            with span("serve.step_wait"):
+                out_np = np.asarray(out)
+                finite_np = np.asarray(finite)
 
-            # the per-step health guard: a stepped slot whose logits came
-            # back non-finite (or was chaos-flagged as such) commits
-            # NOTHING — its lengths never advance, so the retry recomputes
-            # the very same rows deterministically
-            faulted: set[int] = set()
-            for slot in list(sched.active):
-                if stall_arr[slot] or bool(finite_np[slot]):
-                    continue
-                faulted.add(slot)
-            if self.faults is not None:
+            with span("serve.commit"):
+                # the per-step health guard: a stepped slot whose logits
+                # came back non-finite (or was chaos-flagged as such)
+                # commits NOTHING — its lengths never advance, so the
+                # retry recomputes the very same rows deterministically
+                faulted: set[int] = set()
                 for slot in list(sched.active):
-                    if (not stall_arr[slot] and slot not in faulted
-                            and self.faults.fire("logits.nan")):
-                        faulted.add(slot)
+                    if stall_arr[slot] or bool(finite_np[slot]):
+                        continue
+                    faulted.add(slot)
+                if self.faults is not None:
+                    for slot in list(sched.active):
+                        if (not stall_arr[slot] and slot not in faulted
+                                and self.faults.fire("logits.nan")):
+                            faulted.add(slot)
 
-            # acceptance walk: draft i is valid iff it equals the verify
-            # step's argmax after consuming draft i-1 (and every earlier
-            # draft held) — the longest such prefix commits
-            n_cand = np.ones((B,), np.int32)
-            written = {}
-            slot_steps += len(sched.active) - len(stalled)
-            for slot in list(sched.active):
-                if stall_arr[slot]:
-                    n_cand[slot] = 0        # sat out: commit nothing
-                    continue
-                req = sched.active[slot]
-                if slot in faulted:
-                    # faulted: exactly the stall contract (no advance, no
-                    # commit, pending untouched) plus retry accounting —
-                    # capped backoff, then terminal FAILED with all pages
-                    # released once the streak passes max_retries
-                    n_cand[slot] = 0
-                    req.retries += 1
-                    req.fail_streak += 1
-                    if req.fail_streak > self.health.policy.max_retries:
-                        fail_request(slot, req,
-                                     "non-finite logits past max_retries")
-                    else:
-                        req.backoff = self.health.policy.backoff(
-                            req.fail_streak)
-                        if tel is not None:
-                            tel.tracer.begin(req.rid, "RETRY_BACKOFF",
-                                             now(), steps=req.backoff)
-                    continue
-                req.fail_streak = 0
-                len0 = int(pool.lengths[slot])
-                # rows past the reach of the slot's *reserved* pages went
-                # to the null page; their logits are garbage, so cap
-                # acceptance before them
-                written[slot] = min(S, pool.reserved_tokens(slot) - len0)
-                pool.advance(slot, written[slot])
-                a = 0
-                while (a < min(D, written[slot] - 1)
-                       and toks_in[slot, a + 1] == out_np[slot, a]):
-                    a += 1
-                n_cand[slot] = a + 1
-            consumed = self._commit_tokens(sched, out_np, n_cand, pending,
-                                           active, now(), release_slot)
-            committed_total += sum(consumed.values())
-            for slot, c in consumed.items():
-                if slot in sched.active:    # finished slots already released
-                    pool.rollback(slot, written[slot] - c)
-            dt_step = time.perf_counter() - t_step0
-            # fold the step into the health ladder, then act on it: enter
-            # the safe-plan fallback while degraded, restore on recovery
-            self.health.note_step(dt_step, n_slot_faults=len(faulted))
-            if self.health.degraded:
-                self._enter_fallback()
-            else:
-                self._exit_fallback()
-            self._tap_step(n_act, sum(consumed.values()), dt_step)
-            if tel is not None:
-                tel.on_step(steps, t_step0 - t0, dt_step,
-                            sum(consumed.values()), n_act,
-                            pool.allocator.n_free, len(faulted),
-                            self._bucket_class.get(load_bucket(n_act), ""))
-        except BaseException as e:
-            # engine-internal error mid-serve: the failure domain is the
-            # whole trace, but the POOL must outlive it — release every
-            # resident's pages (best-effort per slot: one bad row must not
-            # strand the rest) and re-raise only after the allocator's
-            # invariants are re-checked, so a later serve on this engine
-            # starts from a provably consistent pool
-            for slot, req in (list(sched.prefilling.items())
-                              + list(sched.active.items())):
-                try:
-                    pool.release(slot)
-                except Exception:
-                    pass
-                sched.fail(req, now(), f"engine aborted: "
-                                       f"{type(e).__name__}: {e}")
-            pool.allocator.check_invariants()
-            raise
+                # acceptance walk: draft i is valid iff it equals the
+                # verify step's argmax after consuming draft i-1 (and
+                # every earlier draft held) — the longest such prefix
+                # commits
+                n_cand = np.ones((B,), np.int32)
+                written = {}
+                slot_steps += len(sched.active) - len(stalled)
+                for slot in list(sched.active):
+                    if stall_arr[slot]:
+                        n_cand[slot] = 0    # sat out: commit nothing
+                        continue
+                    req = sched.active[slot]
+                    if slot in faulted:
+                        # faulted: exactly the stall contract (no advance,
+                        # no commit, pending untouched) plus retry
+                        # accounting — capped backoff, then terminal
+                        # FAILED with all pages released once the streak
+                        # passes max_retries
+                        n_cand[slot] = 0
+                        req.retries += 1
+                        req.fail_streak += 1
+                        if req.fail_streak > self.health.policy.max_retries:
+                            fail_request(slot, req,
+                                         "non-finite logits past "
+                                         "max_retries")
+                        else:
+                            req.backoff = self.health.policy.backoff(
+                                req.fail_streak)
+                            if tel is not None:
+                                tel.tracer.begin(req.rid, "RETRY_BACKOFF",
+                                                 now(), steps=req.backoff)
+                        continue
+                    req.fail_streak = 0
+                    len0 = int(pool.lengths[slot])
+                    # rows past the reach of the slot's *reserved* pages
+                    # went to the null page; their logits are garbage, so
+                    # cap acceptance before them
+                    written[slot] = min(S, pool.reserved_tokens(slot) - len0)
+                    pool.advance(slot, written[slot])
+                    a = 0
+                    while (a < min(D, written[slot] - 1)
+                           and toks_in[slot, a + 1] == out_np[slot, a]):
+                        a += 1
+                    n_cand[slot] = a + 1
+                consumed = self._commit_tokens(sched, out_np, n_cand,
+                                               pending, active, now(),
+                                               release_slot)
+                n_tokens = sum(consumed.values())
+                committed_total += n_tokens
+                for slot, c in consumed.items():
+                    if slot in sched.active:  # finished: released
+                        pool.rollback(slot, written[slot] - c)
+            if sp is not None:
+                sp.set_metadata(tokens=n_tokens)
+
+            with span("serve.tap"):
+                dt_step = time.perf_counter() - t_step0
+                # fold the step into the health ladder, then act on it:
+                # enter the safe-plan fallback while degraded, restore on
+                # recovery
+                self.health.note_step(dt_step, n_slot_faults=len(faulted))
+                if self.health.degraded:
+                    self._enter_fallback()
+                else:
+                    self._exit_fallback()
+                self._tap_step(n_act, n_tokens, dt_step)
+                if tel is not None:
+                    tel.on_step(steps, dt_step, n_tokens, n_act,
+                                pool.allocator.n_free, len(faulted),
+                                self._bucket_class.get(load_bucket(n_act),
+                                                       ""))
+            return True
+
+        with span("serve.loop"):
+            # the loop's clock starts inside this span's start: a time on
+            # it (arrival_s, t_admit, t_prefilled, t_first, the request
+            # tracer's spans) sits on the profiler's clock at the span's
+            # start plus that time
+            t0 = time.perf_counter()
+            try:
+                while not sched.done():
+                    with span("serve.pass") as sp:
+                        if not run_pass(sp):
+                            break
+            except BaseException as e:
+                # engine-internal error mid-serve: the failure domain is
+                # the whole trace, but the POOL must outlive it — release
+                # every resident's pages (best-effort per slot: one bad
+                # row must not strand the rest) and re-raise only after
+                # the allocator's invariants are re-checked, so a later
+                # serve on this engine starts from a provably consistent
+                # pool
+                for slot, req in (list(sched.prefilling.items())
+                                  + list(sched.active.items())):
+                    try:
+                        pool.release(slot)
+                    except Exception:
+                        pass
+                    sched.fail(req, now(), f"engine aborted: "
+                                           f"{type(e).__name__}: {e}")
+                pool.allocator.check_invariants()
+                raise
         # serve-end audit: refcounts match owners AND no live page is
         # stranded outside the prefix index (every slot released)
         pool.allocator.check_invariants()

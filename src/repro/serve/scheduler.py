@@ -80,6 +80,9 @@ class Request:
     prefill_pos: int = 0                # prompt tokens already prefilled
     out_tokens: list = dataclasses.field(default_factory=list)
     t_admit: Optional[float] = None     # seconds since serve() start
+    t_prefilled: Optional[float] = None  # prompt fully prefilled (the
+                                        # last admission's, after a
+                                        # preemption)
     t_first: Optional[float] = None     # first generated token
     t_done: Optional[float] = None
     n_preempts: int = 0                 # times evicted by the governor
@@ -164,6 +167,17 @@ class Scheduler:
             return self.preempted[0]
         return self._queue[0] if self.has_ready(now_s) else None
 
+    def n_waiting(self, now_s: float) -> int:
+        """Requests arrived by ``now_s`` and not resident: the preempted
+        and the arrival queue's due head (the queue is in arrival
+        order)."""
+        n = len(self.preempted)
+        for req in self._queue:
+            if req.arrival_s > now_s:
+                break
+            n += 1
+        return n
+
     def pop_ready(self, now_s: float) -> Optional[Request]:
         if self.preempted:
             req = self.preempted.popleft()
@@ -202,6 +216,7 @@ class Scheduler:
                              f"slot {req.slot}")
         del self.prefilling[req.slot]
         req.state = RequestState.DECODE
+        req.t_prefilled = now_s
         self.active[req.slot] = req
         if self.tracer is not None:
             self.tracer.end(req.rid, "PREFILL", now_s)

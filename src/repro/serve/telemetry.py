@@ -1,39 +1,36 @@
-"""Serve telemetry: request span tracing, step metrics ring, exporters.
+"""Serve telemetry: serve-loop spans on the profiler's clock, request span
+tracing, latency sketches, exporters.
 
 The paper's loop is *measure hardware counters -> decide execution
 parameters*; LIKWID (the measurement tool the paper builds on) works
 because its overhead is low enough to leave enabled.  This module is the
-serve engine's LIKWID layer: an always-on-capable observability
-subsystem whose **disabled path costs one ``is not None`` attribute
-check** (the same contract as :class:`repro.serve.faults.FaultInjector`)
-and whose enabled path is bounded host memory regardless of serve
-length.
+serve engine's LIKWID layer.  Its pieces:
 
-Four pieces:
+* :func:`span` — the serve loop's phase spans (``serve.pass``,
+  ``serve.step_wait``, ...) as ``jax.profiler.TraceAnnotation`` events:
+  profiler TraceMe's, recorded into the same trace as the device's ops
+  and so on the device trace's clock.  Always compiled in, recorded only
+  while a profiler session runs; with none running, :func:`span` makes
+  one static call and returns a shared null context.  Counters attach to
+  an open span through ``set_metadata``.  :class:`GcSpans` adds one
+  ``python.gc`` span per garbage collection.
 
 * :class:`SpanTracer` — per-request typed spans (QUEUED, PREFILL,
-  PREFILL_CHUNK, DECODE, PREEMPTED, RETRY_BACKOFF, COW, SWAP) recorded
-  at the existing scheduler/engine/governor transition points and
-  exportable as Chrome trace-event JSON (:meth:`SpanTracer
-  .chrome_trace`), loadable directly in Perfetto / ``chrome://tracing``.
-  Spans per request nest and cover admission -> terminal (the lifecycle
-  property tests pin this).  The span store is capped; overflow is
-  counted, never silently truncated.
-
-* :class:`MetricsRing` — a fixed-capacity per-step metrics ring
-  (latency, tokens, occupancy, free pages, faults, resolved plan class)
-  with the same stride-doubling in-place decimation as the governor's
-  ``free_page_trace``: when the buffer fills, every other sample is
-  dropped and the stride doubles, so a serve of any length holds
-  ``<= cap`` samples.  Exact aggregates (count / sum / min / max) are
-  tracked on every append — decimation never loses the extremes.
+  PREFILL_CHUNK, DECODE, PREEMPTED, RETRY_BACKOFF, COW) recorded at the
+  existing scheduler/engine/governor transition points and exportable as
+  Chrome trace-event JSON (:meth:`SpanTracer.chrome_trace`), loadable
+  directly in Perfetto / ``chrome://tracing``.  Spans per request nest
+  and cover admission -> terminal (the lifecycle property tests pin
+  this).  The span store is capped; overflow is counted, never silently
+  truncated.
 
 * :class:`LatencySketch` — a log-bucketed quantile sketch (HDR-histogram
   style, sparse dict of geometric buckets).  ``quantile(p)`` returns the
   upper edge of the bucket holding the ``ceil(p*n)``-th sample, so the
   estimate ``v`` brackets a true order statistic:
   ``exact <= v <= exact * growth`` — a provable relative-error bound in
-  O(log(range)/log(growth)) memory, no samples retained.
+  O(log(range)/log(growth)) memory, no samples retained; count, sum, min
+  and max stay exact.
 
 * Exporters — :func:`prometheus_text` flattens the engine's
   :meth:`~repro.serve.engine.Engine.observability` aggregate (plus the
@@ -41,6 +38,11 @@ Four pieces:
   :meth:`Telemetry.event` feeds a bounded, levelled event buffer that
   can stream as JSONL (the structured replacement for the launcher's
   scattered ``[pool]``/``[spec]``/``[scan]``/``[failures]`` lines).
+
+:class:`Telemetry` (tracer, sketches, events) is off by default, and its
+disabled path costs one ``is not None`` attribute check (the same
+contract as :class:`repro.serve.faults.FaultInjector`); its enabled path
+is bounded host memory regardless of serve length.
 
 The latency signals also close the paper's loop: the engine's
 measurement tap quantizes the windowed step-latency p99 and mean queue
@@ -50,20 +52,68 @@ PlanDecider can learn from observed latency, not just tok/s.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import time
 from collections import deque
 from typing import Any, Optional
 
-#: Typed span kinds (the request-lifecycle vocabulary).  SWAP is defined
-#: ahead of tiered KV memory (ROADMAP item 5): the tracer, exporters and
-#: tests already accept it, so the swap engine only has to emit it.
+from jax.profiler import TraceAnnotation
+
+#: Typed span kinds (the request-lifecycle vocabulary).
 SPAN_KINDS = ("QUEUED", "PREFILL", "PREFILL_CHUNK", "DECODE",
-              "PREEMPTED", "RETRY_BACKOFF", "COW", "SWAP")
+              "PREEMPTED", "RETRY_BACKOFF", "COW")
 
 #: JSONL event levels (Prometheus-ish severity ladder).
 LEVELS = {"debug": 10, "info": 20, "warning": 30}
+
+
+# ---------------------------------------------------------------------------
+# Serve-loop spans on the profiler's clock
+# ---------------------------------------------------------------------------
+_recording = TraceAnnotation.is_enabled     # True while a profiler runs
+_NULL = contextlib.nullcontext()            # shared: ``as`` binds None
+
+
+def span(name: str):
+    """A profiler span named ``name``: a ``jax.profiler.TraceAnnotation``
+    (a TraceMe) while a profiler session records, so that its start, end
+    and counters land in the same trace as the device's ops; otherwise
+    one shared null context whose ``as`` target is None, and nothing is
+    allocated.  Counters attach through the bound span's
+    ``set_metadata``, which callers compute only when the span is not
+    None::
+
+        with span("serve.pass") as sp:
+            ...
+            if sp is not None:
+                sp.set_metadata(tokens=n)
+    """
+    if not _recording():
+        return _NULL
+    return TraceAnnotation(name)
+
+
+class GcSpans:
+    """A ``gc.callbacks`` entry that records one ``python.gc`` span per
+    collection (counter: ``generation``) while a profiler records.
+    Collections never nest, so one open span is all it holds."""
+
+    __slots__ = ("_open",)
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if _recording():
+                self._open = TraceAnnotation(
+                    "python.gc", generation=info["generation"])
+                self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
 
 
 # ---------------------------------------------------------------------------
@@ -127,71 +177,6 @@ class LatencySketch:
                 "min": self.min or 0.0, "max": self.max or 0.0,
                 "p50": self.quantile(0.50), "p90": self.quantile(0.90),
                 "p99": self.quantile(0.99)}
-
-
-# ---------------------------------------------------------------------------
-# Step metrics ring
-# ---------------------------------------------------------------------------
-class MetricsRing:
-    """Bounded per-step metrics buffer with stride-doubling decimation.
-
-    Each record is ``(step, t_s, dt_s, tokens, n_active, free_pages,
-    n_faults, plan_class)``.  Appends follow the governor's
-    ``free_page_trace`` discipline: only every ``stride``-th record is
-    kept, and when the buffer still reaches ``cap`` it is decimated in
-    place (``[::2]``) and the stride doubles — O(cap) host memory for a
-    serve of any length.  Aggregates (count, token total, latency
-    min/max/sum) are updated on *every* append, so decimation never
-    loses the extremes (property-tested).
-    """
-
-    FIELDS = ("step", "t_s", "dt_s", "tokens", "n_active", "free_pages",
-              "n_faults", "plan_class")
-
-    def __init__(self, cap: int = 256):
-        if cap < 2:
-            raise ValueError(f"ring cap must be >= 2, got {cap}")
-        self.cap = cap
-        self.records: list[tuple] = []
-        self.stride = 1
-        self._skip = 0
-        # exact aggregates, independent of decimation
-        self.count = 0
-        self.tokens_total = 0
-        self.faults_total = 0
-        self.dt_sum = 0.0
-        self.dt_min: Optional[float] = None
-        self.dt_max: Optional[float] = None
-
-    def append(self, step: int, t_s: float, dt_s: float, tokens: int,
-               n_active: int, free_pages: int, n_faults: int,
-               plan_class: str = "") -> None:
-        self.count += 1
-        self.tokens_total += tokens
-        self.faults_total += n_faults
-        self.dt_sum += dt_s
-        if self.dt_min is None or dt_s < self.dt_min:
-            self.dt_min = dt_s
-        if self.dt_max is None or dt_s > self.dt_max:
-            self.dt_max = dt_s
-        if self._skip == 0:
-            self.records.append((step, t_s, dt_s, tokens, n_active,
-                                 free_pages, n_faults, plan_class))
-            if len(self.records) >= self.cap:
-                self.records = self.records[::2]
-                self.stride *= 2
-        self._skip = (self._skip + 1) % self.stride
-
-    def __len__(self) -> int:
-        return len(self.records)
-
-    def summary(self) -> dict:
-        return {"steps": self.count, "kept": len(self.records),
-                "stride": self.stride, "tokens": self.tokens_total,
-                "faults": self.faults_total,
-                "dt_sum_s": self.dt_sum,
-                "dt_min_s": self.dt_min or 0.0,
-                "dt_max_s": self.dt_max or 0.0}
 
 
 # ---------------------------------------------------------------------------
@@ -288,29 +273,27 @@ class SpanTracer:
 # The aggregate subsystem (what the engine holds as ``self.telemetry``)
 # ---------------------------------------------------------------------------
 class Telemetry:
-    """Tracer + ring + sketches + levelled event log for one engine.
+    """Tracer + sketches + levelled event log for one engine.
 
-    Per-trace state (spans, ring, sketches, counters) is reset by
+    Per-trace state (spans, sketches, counters) is reset by
     :meth:`start_trace` at every ``serve()`` entry, so exports reflect
     the most recent trace — matching ``decisions_log``/health semantics.
     """
 
     def __init__(self, level: str = "info", log_out: str = "",
-                 span_cap: int = 65536, ring_cap: int = 256,
-                 event_cap: int = 4096):
+                 span_cap: int = 65536, event_cap: int = 4096):
         if level not in LEVELS:
             raise ValueError(f"unknown log level {level!r} "
                              f"(expected one of {sorted(LEVELS)})")
         self.level = level
         self.tracer = SpanTracer(cap=span_cap)
-        self.ring = MetricsRing(cap=ring_cap)
         self.step_latency = LatencySketch()
         self.queue_delay = LatencySketch()
         self.ttft = LatencySketch()
         self.counts: dict[str, int] = {}
         self.events: deque = deque(maxlen=event_cap)
         self.events_total = 0
-        self._span_cap, self._ring_cap = span_cap, ring_cap
+        self._span_cap = span_cap
         self._t0 = time.perf_counter()
         self._log_f = open(log_out, "w") if log_out else None
 
@@ -318,7 +301,6 @@ class Telemetry:
     def start_trace(self) -> None:
         """Fresh per-serve state (the event log stream stays open)."""
         self.tracer = SpanTracer(cap=self._span_cap)
-        self.ring = MetricsRing(cap=self._ring_cap)
         self.step_latency = LatencySketch()
         self.queue_delay = LatencySketch()
         self.ttft = LatencySketch()
@@ -334,13 +316,11 @@ class Telemetry:
     def count(self, key: str, n: int = 1) -> None:
         self.counts[key] = self.counts.get(key, 0) + n
 
-    def on_step(self, step: int, t_s: float, dt_s: float, tokens: int,
+    def on_step(self, step: int, dt_s: float, tokens: int,
                 n_active: int, free_pages: int, n_faults: int,
                 plan_class: str = "") -> None:
-        """One decode step: feed the ring + latency sketch, and (at debug
-        level) a structured per-step event."""
-        self.ring.append(step, t_s, dt_s, tokens, n_active, free_pages,
-                         n_faults, plan_class)
+        """One decode step: feed the latency sketch, and (at debug level)
+        a structured per-step event."""
         self.step_latency.add(dt_s)
         if LEVELS[self.level] <= LEVELS["debug"]:
             self.event("step", level="debug", step=step, dt_s=round(dt_s, 6),
@@ -383,7 +363,6 @@ class Telemetry:
             "spans_dropped": self.tracer.dropped,
             "events": self.events_total,
             "counts": dict(sorted(self.counts.items())),
-            "ring": self.ring.summary(),
             "step_latency_s": self.step_latency.summary(),
             "queue_delay_s": self.queue_delay.summary(),
             "ttft_s": self.ttft.summary(),

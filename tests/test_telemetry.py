@@ -1,8 +1,8 @@
-"""Serve telemetry: metrics-ring and quantile-sketch properties, span
-lifecycle invariants on real serves (including preemption), exporter
-validity (Chrome trace / Prometheus text / JSONL), telemetry-on
-bit-identity, the zero-allocation disabled path, and the residual
-measurement-tap flush fix."""
+"""Serve telemetry: quantile-sketch properties, span lifecycle invariants
+on real serves (including preemption), exporter validity (Chrome trace /
+Prometheus text / JSONL), telemetry-on bit-identity, the zero-allocation
+disabled path, the residual measurement-tap flush fix, and the serve
+loop's profiler spans and counters."""
 import json
 import math
 import tracemalloc
@@ -19,47 +19,8 @@ from repro.configs.registry import get_config
 from repro.models.model import build
 from repro.serve.engine import Engine, ServeConfig
 from repro.serve.scheduler import Request, RequestState
-from repro.serve.telemetry import (LatencySketch, MetricsRing, SpanTracer,
-                                   Telemetry, prometheus_text)
-
-# ---------------------------------------------------------------------------
-# MetricsRing: bounded memory, exact aggregates under decimation
-# ---------------------------------------------------------------------------
-
-
-@settings(max_examples=40)
-@given(n=st.integers(min_value=0, max_value=3000),
-       cap=st.integers(min_value=2, max_value=64),
-       dts=st.lists(st.integers(min_value=1, max_value=10_000_000),
-                    min_size=1, max_size=50))
-def test_ring_bounded_and_aggregates_exact(n, cap, dts):
-    """However many steps are appended, the ring holds <= cap records,
-    while count / token total / dt min / dt max / dt sum stay EXACT —
-    stride decimation drops samples, never extremes."""
-    ring = MetricsRing(cap=cap)
-    appended = []
-    for i in range(n):
-        dt = dts[i % len(dts)] * 1e-6
-        ring.append(i, i * 1e-3, dt, tokens=i % 5, n_active=1 + i % 3,
-                    free_pages=10, n_faults=i % 2, plan_class="c")
-        appended.append(dt)
-    assert len(ring) <= cap
-    assert ring.count == n
-    assert ring.tokens_total == sum(i % 5 for i in range(n))
-    assert ring.faults_total == sum(i % 2 for i in range(n))
-    if n:
-        assert ring.dt_min == min(appended)
-        assert ring.dt_max == max(appended)
-        assert math.isclose(ring.dt_sum, sum(appended), rel_tol=1e-9)
-        # kept records are a genuine subsequence of what was appended
-        # (strictly increasing step ids), so the ring still shows the
-        # serve's shape in order, not an arbitrary sample
-        steps = [r[0] for r in ring.records]
-        assert steps == sorted(set(steps))
-        assert all(0 <= s < n for s in steps)
-    summary = ring.summary()
-    assert summary["steps"] == n and summary["kept"] == len(ring)
-
+from repro.serve.telemetry import (LatencySketch, SpanTracer, Telemetry,
+                                   prometheus_text)
 
 # ---------------------------------------------------------------------------
 # LatencySketch: provable rank/relative-error bound
@@ -234,7 +195,7 @@ def test_span_lifecycle_covers_admission_to_terminal(served):
     tm = res_on["telemetry"]
     assert tm["spans"] == len(eng_on.telemetry.tracer.spans)
     assert tm["spans_dropped"] == 0
-    assert tm["ring"]["steps"] == res_on["steps"]
+    assert tm["step_latency_s"]["count"] == res_on["steps"]
     assert tm["queue_delay_s"]["count"] == len(reqs_on)
     assert tm["ttft_s"]["count"] == len(reqs_on)
     assert tm["counts"]["admissions"] == len(reqs_on)
@@ -380,3 +341,175 @@ def test_residual_tap_flush_not_lost(served):
     assert any(f[-2] > 0 for f in feats), (
         "no observation recorded a quantized step-latency p99")
     assert all(f[-1] >= 0 for f in feats)
+
+
+# ---------------------------------------------------------------------------
+# Serve-loop spans on the profiler's clock
+# ---------------------------------------------------------------------------
+PHASES = ("serve.admit", "serve.chunk_dispatch", "serve.headroom",
+          "serve.step_dispatch", "serve.step_wait", "serve.commit",
+          "serve.tap", "serve.idle")
+
+
+def _program_spans(log_dir):
+    """(name, start_ns, end_ns, counters) of every serve.* / python.gc
+    host event in the profile written under ``log_dir``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(log_dir), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve.") or ev.name == "python.gc":
+                    out.append((ev.name, ev.start_ns, ev.end_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+@pytest.fixture(scope="module")
+def profiled(served, tmp_path_factory):
+    """A warmed paged engine serving multi-chunk prompts under a profiler
+    session — prefills overlap decode steps, and the last arrival leaves
+    the loop idle first; its pool step wrapped to keep each call's
+    ``lengths`` and ``active`` arguments."""
+    model, params, _, _, _, _, _, _, _, _ = served
+    cfg = get_config("stablelm-1.6b").reduced()
+    rng = np.random.default_rng(7)
+    arrivals = (0.0, 0.0, 0.004, 0.008, 0.3)
+
+    def mk():
+        return [Request(rid=i, prompt=rng.integers(
+                    0, cfg.vocab_size, 6 + 5 * i).astype(np.int32),
+                    max_new_tokens=6 + i, arrival_s=t)
+                for i, t in enumerate(arrivals)]
+
+    eng = Engine(model, params, serve_cfg=ServeConfig(
+        max_len=40, max_slots=3, page_size=8, prefill_chunk=8,
+        spec_depth=0))
+    eng.serve(mk())                                 # compile outside
+    calls = []
+    step = eng._pool_step
+
+    def kept(params, pages, toks, bt, lengths, act, key):
+        calls.append((np.asarray(lengths), np.asarray(act)))
+        return step(params, pages, toks, bt, lengths, act, key)
+
+    eng._pool_step = kept
+    log_dir = tmp_path_factory.mktemp("profile")
+    reqs = mk()
+    with jax.profiler.trace(str(log_dir)):
+        res = eng.serve(reqs)
+    return _program_spans(log_dir), res, reqs, calls
+
+
+def test_serve_spans_nest_run_loop_pass_phase(profiled):
+    spans, _, _, _ = profiled
+
+    def within(inner, outer):
+        return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+    run, = [s for s in spans if s[0] == "serve.run"]
+    loop, = [s for s in spans if s[0] == "serve.loop"]
+    passes = [s for s in spans if s[0] == "serve.pass"]
+    assert within(loop, run) and passes
+    assert all(within(p, loop) for p in passes)
+    phases = [s for s in spans if s[0] in PHASES]
+    assert {s[0] for s in phases} >= {
+        "serve.admit", "serve.chunk_dispatch", "serve.headroom",
+        "serve.step_dispatch", "serve.step_wait", "serve.commit",
+        "serve.tap", "serve.idle"}
+    for ph in phases:
+        assert sum(within(ph, p) for p in passes) == 1, ph
+    # passes follow one another; the phases of a pass do too
+    for a, b in zip(passes, passes[1:]):
+        assert a[2] <= b[1]
+    for a, b in zip(phases, phases[1:]):
+        assert a[2] <= b[1]
+
+
+def test_serve_pass_counters_match_the_serve(profiled):
+    spans, res, reqs, _ = profiled
+    passes = [s[3] for s in spans if s[0] == "serve.pass"]
+    stepped = [c for c in passes if "step" in c]
+    assert len(stepped) == res["steps"]
+    assert [c["step"] for c in stepped] == list(range(1, res["steps"] + 1))
+    assert sum(c.get("tokens", 0) for c in passes) == sum(
+        len(r.out_tokens) for r in reqs)
+    chunks = [s[3] for s in spans if s[0] == "serve.chunk_dispatch"]
+    assert sum(c.get("chunks", 0) for c in passes) == len(chunks)
+    # each request's chunks cover its prompt but the pending last token
+    for r in reqs:
+        mine = [c for c in chunks if c["rid"] == r.rid]
+        assert [c["base"] for c in mine] == list(range(
+            0, r.prompt.size - 1, 8))
+        assert sum(c["tokens"] for c in mine) == r.prompt.size - 1
+    admitted = [s[3] for s in spans if s[0] == "serve.admit"]
+    assert sum(c["admitted"] for c in admitted) == len(reqs)
+
+
+def test_step_dispatch_live_tokens_are_the_steps_lengths(profiled):
+    spans, res, _, calls = profiled
+    got = [s[3] for s in spans if s[0] == "serve.step_dispatch"]
+    assert len(got) == len(calls) == res["steps"]
+    for c, (lengths, act) in zip(got, calls):
+        assert c["live_tokens"] == int(lengths[act].sum())
+        assert c["active"] == int(act.sum())
+
+
+def test_program_times_sit_on_the_trace_clock(profiled):
+    """The loop's clock starts at the serve.loop span's start: a request's
+    admission time placed on the trace falls inside a serve.admit span,
+    and its prefill time inside its own last chunk dispatch."""
+    spans, _, reqs, _ = profiled
+    t0 = next(s[1] for s in spans if s[0] == "serve.loop")
+
+    def inside(t_s, name, **counters):
+        t = t0 + t_s * 1e9
+        return any(s[0] == name and s[1] <= t <= s[2]
+                   and all(s[3].get(k) == v for k, v in counters.items())
+                   for s in spans)
+
+    for r in reqs:
+        assert inside(r.t_admit, "serve.admit")
+        assert inside(r.t_prefilled, "serve.chunk_dispatch", rid=r.rid)
+        assert r.t_admit < r.t_prefilled < r.t_first
+
+
+def test_no_profiler_session_no_span():
+    from repro.serve import telemetry
+    with telemetry.span("serve.pass") as sp:
+        assert sp is None
+    assert telemetry.span("serve.pass") is telemetry.span("serve.commit")
+
+
+def test_gc_callback_records_python_gc_span(tmp_path):
+    import gc
+    from repro.serve.telemetry import GcSpans
+    cb = GcSpans()
+    gc.callbacks.append(cb)
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            gc.collect(1)
+    finally:
+        gc.callbacks.remove(cb)
+    got = [s for s in _program_spans(tmp_path) if s[0] == "python.gc"]
+    assert got and any(s[3].get("generation") == 1 for s in got)
+    assert all(s[2] >= s[1] for s in got)
+
+
+def test_start_decode_stamps_t_prefilled():
+    from repro.serve.scheduler import Scheduler
+    sched = Scheduler()
+    req = Request(rid=0, prompt=np.arange(4, dtype=np.int32),
+                  max_new_tokens=2)
+    sched.submit(req)
+    sched.pop_ready(0.0)
+    sched.bind_prefill(req, 0, 0.5)
+    assert req.t_prefilled is None
+    sched.start_decode(req, 1.25)
+    assert (req.t_admit, req.t_prefilled) == (0.5, 1.25)
