@@ -12,8 +12,11 @@ Phases, all in this one process (it holds the chip and starts no child):
 * serve: 8 requests, 256-token prompts, 32-64 new tokens, 64-token prefill
   chunks, on the paged KV pool under the default (gather) attention plan.
 * kernel: the same trace under the ``attn_paged_kernel`` candidate's plan.
-  The compiled step must hold the Pallas kernel (``tpu_custom_call``), and
-  the kernel must match the ``kernels/ref.py`` oracle at these widths.
+  The step must resolve as the engine says: on a TPU the compiled kernel
+  takes only heads of whole 128-lane tiles, so stablelm-1.6b's 64-wide
+  heads resolve to the gather (no ``tpu_custom_call``) over the pin.  The
+  kernel must match the ``kernels/ref.py`` oracle at the model's head
+  counts, its head width rounded up to whole lane tiles.
 * autotune: the same trace with ``--online-retrain``, so measure -> decide
   runs on the counters parsed from the TPU step's HLO.
 * four-chips (only with ``--four-chips``): the trace on the engine at tp=4
@@ -151,14 +154,15 @@ def serve_once(args, ck: Checks, clock: CompileClock, label: str, *extra):
 
 def kernel_oracle(ck: Checks, cfg, page_size: int, on_tpu: bool, seed: int):
     """``paged_attention_mq`` (compiled on TPU) against the f32 oracle at
-    the model's head layout, for plain decode (S=1) and the spec2 verify
-    width (S=3)."""
+    the model's head counts, the head width rounded up to whole 128-lane
+    tiles (what the compiled kernel takes), for plain decode (S=1) and the
+    spec2 verify width (S=3)."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops, ref
     kvh = cfg.n_kv_heads
     g = cfg.n_heads // kvh
-    hd = cfg.head_dim or cfg.d_model // cfg.n_heads
+    hd = -(-cfg.resolved_head_dim // 128) * 128
     B, MP = TRACE["slots"], 32
     n_pages = B * MP + 1
     kk, kv, kq, kb = jax.random.split(jax.random.PRNGKey(seed), 4)
@@ -198,15 +202,22 @@ def phase_kernel(args, ck, clock, on_tpu, state):
             f.write(plan.to_json())
         engine, _, toks = serve_once(args, ck, clock, "kernel",
                                      "--plan", path)
+    from repro.kernels.paged_attention import compiles_head_dim
+    cfg, page_size = engine.model.cfg, engine._pool.page_size
+    want = ("paged" if compiles_head_dim(cfg.resolved_head_dim)
+            or not on_tpu else "gather")
+    impl = engine.observability()["step"]["attn_impl"]
+    ck(f"kernel: the pinned step resolves to {want!r}", impl == want,
+       f"attn_impl={impl!r} head_dim={cfg.resolved_head_dim}")
     hlo = engine._pool_step.as_text()
     if on_tpu:
-        ck("kernel: compiled step holds the Pallas kernel",
-           "tpu_custom_call" in hlo,
+        ck(f"kernel: compiled step {'holds' if want == 'paged' else 'lacks'}"
+           " the Pallas kernel",
+           ("tpu_custom_call" in hlo) == (want == "paged"),
            f"tpu_custom_call x{hlo.count('tpu_custom_call')}")
     else:
         print("[check] skipped tpu_custom_call: kernels run in interpret "
               "mode off the TPU")
-    cfg, page_size = engine.model.cfg, engine._pool.page_size
     del engine
     gc.collect()
     kernel_oracle(ck, cfg, page_size, on_tpu, args.seed)

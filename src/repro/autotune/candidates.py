@@ -71,7 +71,7 @@ def default_candidates(kind: str = "train") -> list[Candidate]:
             Candidate("kv_head_shard", RegionConfig(
                 rules={"kv_seq": None, "kv_heads": "model"}), "attn"),
             # paged-KV layout granularity (pool rebuild) and the paged
-            # Pallas kernel's inner KV tile (step rebuild only)
+            # Pallas kernel's tokens per compute block (step rebuild only)
             Candidate("attn_page16", RegionConfig(page_size=16), "attn"),
             Candidate("attn_page64", RegionConfig(page_size=64), "attn"),
             Candidate("attn_paged_kernel", RegionConfig(attn_impl="paged"),

@@ -110,8 +110,11 @@ class RegionConfig:
     moe_impl: str = ""      # '' = default ('einsum'), or 'scatter'
     ssm_impl: str = ""      # '' = default ('scan'), or 'chunked' (matmul SSD)
     page_size: int = 0      # paged-KV block granularity, tokens (0 = default)
-    attn_impl: str = ""     # decode attention: '' = gather, 'paged' = Pallas
-                            # paged-attention kernel (block_k = its KV tile)
+    attn_impl: str = ""     # paged decode attention: '' = auto (the engine
+                            # resolves: 'paged' on a TPU at tp 1, else
+                            # 'gather'); 'gather' = dense gather of every
+                            # block-table page; 'paged' = Pallas kernel
+                            # over live pages (block_k = tokens per block)
     spec_depth: int = -1    # speculative decode draft depth per pool step
                             # (-1 = knob unset; 0 = no speculation; N>0 =
                             # draft N tokens, verify with q_len N+1)

@@ -336,12 +336,13 @@ def apply_attention_paged_decode(cfg, p, x, pages, block_tables, lengths,
     rows are rolled back host-side (lengths truncate; the rows are
     overwritten by the next step's writes before any mask admits them).
 
-    The attention impl is a region knob: the default gathers each slot's
+    The attention impl is a region knob, resolved by the engine
+    (``Engine.attn_impl_for``): 'gather' (or '') gathers each slot's
     pages dense and runs the grouped-GQA einsum (identical math to the
     slot path's ``apply_attention_decode``); ``attn_impl='paged'`` calls
-    the multi-query Pallas paged-attention kernel, which DMAs K/V
-    page-by-page through the block table with a ``block_k``-sized inner
-    tile, all S queries sharing each DMA.
+    the multi-query Pallas paged-attention kernel, which copies only each
+    slot's live pages through the block table, ``block_k`` tokens per
+    compute block, all S queries sharing each copy.
     """
     with region(name) as rpath:
         B, S, _ = x.shape
@@ -369,8 +370,11 @@ def apply_attention_paged_decode(cfg, p, x, pages, block_tables, lengths,
         if rc.attn_impl == "paged":
             from repro.kernels import ops
             qg = q.reshape(B, S, kvh, grp, hd)
+            # an inactive slot's row is all null page (page 0, which no
+            # request owns): at length 0 the kernel copies nothing for it
+            seen = jnp.where(block_tables[:, 0] > 0, lengths + 1, 0)
             attn = ops.paged_attention_mq(qg, k_pages, v_pages, block_tables,
-                                          lengths + 1, block_k=rc.block_k)
+                                          seen, block_k=rc.block_k)
             attn = attn.astype(x.dtype)
         elif S == 1:
             qg = q.reshape(B, kvh, grp, hd)

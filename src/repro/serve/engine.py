@@ -24,10 +24,12 @@ Two paths share one Engine:
   into the same HBM.  Prompts prefill in ``prefill_chunk``-sized pieces
   *interleaved* with pool decode steps (at most ``prefill_chunks_per_step``
   chunks between consecutive steps), so a long prompt no longer stalls
-  every in-flight decode.  The decode attention gathers K/V through the
-  block table — grouped-GQA einsum by default, or the Pallas
-  paged-attention kernel when the plan sets ``attn_impl='paged'`` (its
-  inner KV tile is ``block_k``).
+  every in-flight decode.  The decode attention reads K/V through the
+  block table: on a TPU at tp 1 the Pallas paged-attention kernel copies
+  only each slot's live pages (``block_k`` tokens per compute block);
+  elsewhere, or when the plan pins ``attn_impl='gather'``, the slots'
+  pages are gathered dense into a grouped-GQA einsum
+  (:meth:`Engine.attn_impl_for`).
 
   **Speculative multi-token decode** (``spec_depth`` > 0, greedy only):
   each pool step drafts up to ``spec_depth`` tokens per active slot by
@@ -106,6 +108,7 @@ from repro.autotune.candidates import canonical
 from repro.autotune.decider import PlanDecider  # noqa: F401  (re-export:
                                                 # moved to repro.autotune)
 from repro.core.policy import RegionConfig, RegionPlan, null_plan
+from repro.kernels.paged_attention import compiles_head_dim, live_pages
 from repro.models.model import Model
 from repro.serve.faults import FaultInjector
 from repro.serve.health import HealthMonitor, HealthPolicy
@@ -334,7 +337,7 @@ class Engine:
     # stay callable on partially-constructed engine shells (tests stub
     # Engine via object.__new__ to exercise them without a model)
     _force_safe = False                 # pin spec0/gather/tp1
-    _fallback = None                    # (step, depth, tp) to restore
+    _fallback = None                    # (step, depth, tp, attn) to restore
 
     def __init__(self, model: Model, params, plan: Optional[RegionPlan] = None,
                  serve_cfg: Optional[ServeConfig] = None, dtree=None):
@@ -377,9 +380,10 @@ class Engine:
                                                     # (scan_mode) resolve
                                                     # against it at call time
         self._pool_steps: dict = {}                 # key -> (compiled, depth,
-                                                    #         tp)
+                                                    #         tp, attn_impl)
         self._pool_step = None
         self._spec_depth = 0                        # depth of _pool_step
+        self._attn_impl = ""                        # its decode attention
         self._pool_rc = None                        # counters of base step
         # -- tensor-parallel serving state -----------------------------------
         self._serve_tp = 1                          # current pages/params
@@ -399,8 +403,9 @@ class Engine:
             max_retries=self.cfg.max_retries,
             watchdog_s=self.cfg.watchdog_s))
         self._force_safe = False                    # pin spec0/gather/tp1
-        self._fallback = None                       # (step, depth, tp) to
-                                                    # restore on recovery
+        self._fallback = None                       # (step, depth, tp,
+                                                    # attn) to restore on
+                                                    # recovery
 
         # -- telemetry (request spans + sketches + exporters) ----------------
         # same zero-overhead contract as the injector: None unless asked
@@ -701,6 +706,50 @@ class Engine:
         shard_kv_heads(kvh, tp)     # the centralised divisibility rule —
         return tp                   # cannot raise after the clamp above
 
+    def attn_impl_for(self, plan: RegionPlan) -> str:
+        """Decode-attention resolution on the paged pool: the plan's
+        attn-region knob pins 'gather' or 'paged'; '' (auto) resolves from
+        what the engine observes — 'paged' (the Pallas kernel, which reads
+        only each slot's live pages, several per copy batch) when the step
+        lowers for a TPU at the resolved tp degree 1, 'gather' otherwise:
+        on the CPU the kernel would run interpreted, and at tp > 1 GSPMD
+        cannot split the kernel's custom call over the kv-head axis.  A
+        degraded engine (``_force_safe``) pins 'gather' ahead of
+        everything, and so do heads the compiled kernel refuses
+        (:meth:`_kernel_refuses_heads`), over a 'paged' pin too."""
+        if self._force_safe or self._kernel_refuses_heads():
+            return "gather"
+        impl = plan.config_for("layer0/attn").attn_impl
+        if impl in ("gather", "paged"):
+            return impl
+        if jax.default_backend() == "tpu" and self.tp_for(plan) == 1:
+            return "paged"
+        return "gather"
+
+    def _kernel_refuses_heads(self) -> bool:
+        """On a TPU the compiled kernel takes only heads of whole 128-lane
+        tiles (:func:`compiles_head_dim`); the gather serves the rest,
+        whatever the plan pins."""
+        return (jax.default_backend() == "tpu" and not compiles_head_dim(
+            self.model.cfg.resolved_head_dim))
+
+    @staticmethod
+    def _plan_with_attn_impl(plan: RegionPlan, impl: str,
+                             override: bool = False) -> RegionPlan:
+        """The plan with every unset (auto) attn_impl knob pinned to the
+        resolved impl — the attention region's effective config included
+        — so '' never reaches the model code (mirrors
+        :meth:`_plan_with_scan_mode`).  ``override``: explicit pins are
+        replaced too (heads the compiled kernel refuses)."""
+        import copy
+        plan2 = copy.deepcopy(plan)
+        rcs = plan2.region_configs
+        rcs.setdefault("layer/attn", plan2.config_for("layer/attn"))
+        for key, rc in rcs.items():
+            if override or not rc.attn_impl:
+                rcs[key] = dataclasses.replace(rc, attn_impl=impl)
+        return plan2
+
     def _tp_mesh(self, tp: int):
         """The ("data", "model") mesh a tp degree shards over: the
         engine-level plan's mesh when its model axis already matches
@@ -827,7 +876,7 @@ class Engine:
                                     self.cfg.max_slots)
             self._build_step = self._build_pool_step
         built = self._build_step(self.plan)
-        self._pool_step, self._spec_depth = built[0], built[1]
+        self._pool_step, self._spec_depth, _, self._attn_impl = built
         self._pool_steps[self._step_cache_key(self.plan)] = built
         if ((self.dtree is not None and self.cfg.autoplan)
                 or self.cfg.online_retrain):
@@ -862,8 +911,9 @@ class Engine:
 
         Carries the same always-on health guard as the paged step: a
         per-slot ``finite`` flag over the S logit rows, inactive slots
-        forced healthy.  No page axis to shard, so tp is always 1.
-        Returns (compiled, D, tp=1); the compiled step returns
+        forced healthy.  No page axis to shard, so tp is always 1, and no
+        block table, so there is no attention impl to choose.  Returns
+        (compiled, D, tp=1, attn_impl=""); the compiled step returns
         ``(tokens (B,S), finite (B,), pool)``."""
         model, temp = self.model, self.cfg.temperature
         depth = self.spec_depth_for(plan)
@@ -888,7 +938,7 @@ class Engine:
         return jax.jit(step, donate_argnums=(1,)).lower(
             self.params, self._pool.pool, jnp.zeros((B, S), jnp.int32),
             jnp.zeros((B,), jnp.bool_),
-            jax.random.PRNGKey(0)).compile(), depth, 1
+            jax.random.PRNGKey(0)).compile(), depth, 1, ""
 
     def _build_paged_step(self, plan: RegionPlan):
         """AOT-compile one decode(+verify)+sample step over the paged pool:
@@ -915,14 +965,20 @@ class Engine:
         error next to the decode matmuls).  Inactive slots decode the
         null page and are garbage *by design*, so they are forced
         healthy.  The host commits nothing from a non-finite slot and
-        retries it (see ``_serve_paged``).  Returns (compiled, D, tp);
-        the compiled step returns ``(tokens (B,S), finite (B,), pages)``.
+        retries it (see ``_serve_paged``).
+
+        The decode attention lowers as the RESOLVED impl
+        (:meth:`attn_impl_for`), pinned into the plan so '' never reaches
+        the model code.  Returns (compiled, D, tp, attn_impl); the
+        compiled step returns ``(tokens (B,S), finite (B,), pages)``.
         """
         depth = self.spec_depth_for(plan)
         S = depth + 1
         tp = self.tp_for(plan)
+        impl = self.attn_impl_for(plan)
         self._apply_tp(tp)          # lowering captures the live placement
-        splan = self._serve_plan(plan, tp)
+        splan = self._serve_plan(self._plan_with_attn_impl(
+            plan, impl, override=self._kernel_refuses_heads()), tp)
         mesh = splan.mesh if tp > 1 else None
         step = paged_step_fn(self.model, splan, self.cfg.temperature, mesh)
 
@@ -937,7 +993,7 @@ class Engine:
             args[2:] = [jax.device_put(a, rep) for a in args[2:]]
         compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
         if mesh is None:
-            return compiled, depth, tp
+            return compiled, depth, tp, impl
         rep = NamedSharding(mesh, P())
 
         def call(params, pages, *rest):
@@ -948,7 +1004,7 @@ class Engine:
                             *(jax.device_put(r, rep) for r in rest))
         call.as_text = compiled.as_text         # counters.collect reads the
         call.cost_analysis = compiled.cost_analysis     # HLO through these
-        return call, depth, tp
+        return call, depth, tp, impl
 
     def _chunk_fn(self):
         """Jitted paged prefill-chunk step (pages donated; the block-table
@@ -1099,7 +1155,8 @@ class Engine:
         key = self._step_cache_key(plan)
         if key not in self._pool_steps:
             self._pool_steps[key] = self._build_step(plan)
-        self._pool_step, self._spec_depth, step_tp = self._pool_steps[key]
+        (self._pool_step, self._spec_depth, step_tp,
+         self._attn_impl) = self._pool_steps[key]
         # a cache HIT can still be a tp switch (the decider flipping back
         # to a degree it compiled earlier): reshard the live pool/params
         # to the placement the cached executable was lowered against
@@ -1268,6 +1325,13 @@ class Engine:
             rc.pop("scan_mode", None)
         if self._paged:
             raw["tp"] = self.tp_for(plan)
+            # attn_impl keys on its RESOLVED value: '' and the impl it
+            # resolves to share one executable
+            impl = self.attn_impl_for(plan)
+            refused = self._kernel_refuses_heads()
+            for rc in raw.get("regions", {}).values():
+                rc["attn_impl"] = (not refused and rc.get("attn_impl")
+                                   or impl)
             # the resolved depth rides alongside for the same reason —
             # and because resolution can change while the raw knob (or a
             # ServeConfig pin) does not: a degraded engine (_force_safe)
@@ -1408,6 +1472,7 @@ class Engine:
         if self._paged and self.governor is not None:
             pool = self._pool
             obs["memory"] = self.governor.summary()
+            obs["step"] = {"attn_impl": self._attn_impl}
             # mesh placement: page bytes are per DEVICE (pages shard on
             # kv_heads, so each device holds 1/tp of every page);
             # page/watermark COUNTS are tp-invariant
@@ -1461,7 +1526,7 @@ class Engine:
         plan = copy.deepcopy(self.plan)
         base = plan.region_configs.get("layer/attn", RegionConfig())
         plan.region_configs["layer/attn"] = dataclasses.replace(
-            base, spec_depth=0, attn_impl="", tp_degree=1)
+            base, spec_depth=0, attn_impl="gather", tp_degree=1)
         return plan
 
     def _enter_fallback(self):
@@ -1472,15 +1537,16 @@ class Engine:
         previous (step, depth, tp) is saved for :meth:`_exit_fallback`."""
         if self._fallback is not None or not self._paged:
             return
-        prev = (self._pool_step, self._spec_depth, self._serve_tp)
+        prev = (self._pool_step, self._spec_depth, self._serve_tp,
+                self._attn_impl)
         self._force_safe = True
         plan = self._safe_plan()
         key = self._step_cache_key(plan)
         if key not in self._pool_steps:
             self._pool_steps[key] = self._build_step(plan)
-        step, depth, tp = self._pool_steps[key]
+        step, depth, tp, impl = self._pool_steps[key]
         self._apply_tp(tp)
-        self._pool_step, self._spec_depth = step, depth
+        self._pool_step, self._spec_depth, self._attn_impl = step, depth, impl
         self._fallback = prev
         self.health.taps["fallbacks"] += 1
 
@@ -1489,11 +1555,11 @@ class Engine:
         ask the decider to re-decide on the next step."""
         if self._fallback is None:
             return
-        step, depth, tp = self._fallback
+        step, depth, tp, impl = self._fallback
         self._fallback = None
         self._force_safe = False
         self._apply_tp(tp)
-        self._pool_step, self._spec_depth = step, depth
+        self._pool_step, self._spec_depth, self._attn_impl = step, depth, impl
         self._force_replan = True
 
     def _commit_tokens(self, sched: Scheduler, out_np, n_cand, pending,
@@ -2146,8 +2212,14 @@ class Engine:
                     self._step_params, pool.pages, jnp.asarray(toks_in),
                     bt_dev["arr"], jnp.asarray(lengths), bt_dev["act"], sub)
                 if ph is not None:
+                    if self._attn_impl == "paged":
+                        read = int(live_pages(lengths[eff] + 1, S,
+                                              pool.page_size).sum())
+                    else:
+                        read = int(eff.sum()) * pool.max_pages_per_slot
                     ph.set_metadata(active=int(eff.sum()),
-                                    live_tokens=int(lengths.sum()))
+                                    live_tokens=int(lengths.sum()),
+                                    kv_pages_read=read)
             if (self.faults is not None
                     and self.faults.fire("step.latency")):
                 # artificial latency spike, inside the step's measured

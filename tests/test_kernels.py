@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels import paged_attention as PA
 from repro.kernels.tuned_matmul import tuned_matmul
 
 
@@ -42,8 +43,8 @@ def test_flash_attention(S, D, bq, bk, causal, window, key):
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("ps,MP,bk", [(8, 4, 0), (8, 4, 4), (16, 3, 8),
-                                      (16, 3, 16)])
+@pytest.mark.parametrize("ps,MP,bk", [(8, 4, 0), (8, 4, 8), (16, 3, 16),
+                                      (16, 3, 32)])
 def test_paged_attention(ps, MP, bk, key):
     """Paged decode kernel vs the dense-gather oracle: random non-aliasing
     block tables, mixed lengths (page-aligned, ragged, and zero-length
@@ -71,8 +72,8 @@ def test_paged_attention(ps, MP, bk, key):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("ps,MP,S,bk", [(8, 4, 3, 0), (8, 4, 5, 4),
-                                        (16, 3, 2, 8), (16, 3, 4, 16)])
+@pytest.mark.parametrize("ps,MP,S,bk", [(8, 4, 3, 0), (8, 4, 5, 8),
+                                        (16, 3, 2, 16), (16, 3, 4, 48)])
 def test_paged_attention_multiquery(ps, MP, S, bk, key):
     """Multi-query (speculative verify) paged kernel vs the dense-gather
     oracle: S queries per row share the K/V DMA under the staircase mask
@@ -101,6 +102,115 @@ def test_paged_attention_multiquery(ps, MP, S, bk, key):
     act = np.asarray(lengths) > 0
     np.testing.assert_allclose(np.asarray(out)[act], np.asarray(want)[act],
                                rtol=1e-5, atol=1e-5)
+
+
+def _scattered_tables(rng, lengths, S, ps, MP, P):
+    """Block tables over shuffled, non-contiguous page ids: every slot's
+    live pages (what its last query sees) drawn from a permutation of the
+    pool with every other id skipped."""
+    ids = rng.permutation(np.arange(1, P, 2))
+    bt = np.zeros((len(lengths), MP), np.int32)
+    used = 0
+    for b, n in enumerate(PA.live_pages(np.asarray(lengths), S, ps)):
+        bt[b, :n] = ids[used:used + n]
+        used += n
+    return bt
+
+
+@pytest.mark.parametrize("S", [1, 3])
+@pytest.mark.parametrize("mult", [1, 4, 16])
+def test_paged_attention_live_pages(S, mult, key):
+    """Blocks of several pages over live pages only: lengths 0, 1,
+    page_size - 1, page_size, a block boundary -1/0/+1 and max_len - S in
+    one batch, page ids shuffled and not contiguous, block_k of 1, 4 and
+    16 pages (16 pages: the last block is only partly live).  Slots of
+    length 0 lead, sit between live ones and trail, so the next block's
+    copies skip dead slots in every position."""
+    ps, MP, KVH, G, D = 8, 20, 2, 3, 128
+    bk = mult * ps
+    lengths = np.array([0, 1, ps - 1, 0, 0, ps, bk - 1, bk, bk + 1,
+                        MP * ps - S, 0], np.int32)
+    B = len(lengths)
+    P = 2 * B * MP + 2
+    ks = jax.random.split(key, 3)
+    q = (jax.random.normal(ks[0], (B, S, KVH, G, D)) * 0.5).astype(jnp.float32)
+    kp = (jax.random.normal(ks[1], (P, ps, KVH, D)) * 0.5).astype(jnp.float32)
+    vp = (jax.random.normal(ks[2], (P, ps, KVH, D)) * 0.5).astype(jnp.float32)
+    bt = jnp.asarray(_scattered_tables(np.random.default_rng(mult), lengths,
+                                       S, ps, MP, P))
+    out = ops.paged_attention_mq(q, kp, vp, bt, jnp.asarray(lengths),
+                                 block_k=bk)
+    want = ref.paged_attention_mq(q, kp, vp, bt, jnp.asarray(lengths))
+    act = lengths > 0
+    np.testing.assert_allclose(np.asarray(out)[act], np.asarray(want)[act],
+                               rtol=1e-5, atol=1e-5)
+    # a slot of length 0 computes nothing and writes a finite zero
+    assert np.all(np.asarray(out)[~act] == 0)
+
+
+@pytest.mark.parametrize("S", [1, 3])
+def test_paged_attention_narrow_heads_walk_pages(S, key):
+    """Heads narrower than a lane tile, interpreted: the same kernel walks
+    the same lengths mix over scattered pages, two pages a block, nothing
+    past a slot's live pages (compiled, such heads are refused)."""
+    ps, MP, KVH, G, D = 8, 6, 2, 3, 64
+    lengths = np.array([0, 1, ps - 1, 0, ps, ps + 1, MP * ps - S], np.int32)
+    B = len(lengths)
+    P = 2 * B * MP + 2
+    ks = jax.random.split(key, 3)
+    q = (jax.random.normal(ks[0], (B, S, KVH, G, D)) * 0.5).astype(jnp.float32)
+    kp = (jax.random.normal(ks[1], (P, ps, KVH, D)) * 0.5).astype(jnp.float32)
+    vp = (jax.random.normal(ks[2], (P, ps, KVH, D)) * 0.5).astype(jnp.float32)
+    bt = jnp.asarray(_scattered_tables(np.random.default_rng(S), lengths,
+                                       S, ps, MP, P))
+    out = ops.paged_attention_mq(q, kp, vp, bt, jnp.asarray(lengths),
+                                 block_k=2 * ps)
+    want = ref.paged_attention_mq(q, kp, vp, bt, jnp.asarray(lengths))
+    act = lengths > 0
+    np.testing.assert_allclose(np.asarray(out)[act], np.asarray(want)[act],
+                               rtol=1e-5, atol=1e-5)
+    assert np.all(np.asarray(out)[~act] == 0)
+
+
+def test_paged_attention_compiled_refuses_narrow_heads():
+    """A 64-wide head's page cannot be sliced out of the pool by the
+    compiled kernel: it is refused before Mosaic sees it."""
+    q = jnp.zeros((1, 1, 1, 1, 64), jnp.bfloat16)
+    pages = jnp.zeros((3, 8, 1, 64), jnp.bfloat16)
+    bt = jnp.ones((1, 2), jnp.int32)
+    with pytest.raises(ValueError, match="128-lane"):
+        PA.paged_attention_mq(q, pages, pages, bt, jnp.ones((1,), jnp.int32),
+                              interpret=False)
+
+
+def test_paged_attention_bf16_pool_matches_f32_oracle(key):
+    """The pool's dtype: bf16 K/V enter the MXU as stored, the softmax
+    stays f32 — within bf16 rounding of the f32 oracle."""
+    ps, MP, KVH, G, D, S = 16, 8, 2, 4, 128, 1
+    lengths = np.array([5, 16, 100, MP * ps - S], np.int32)
+    P = 2 * len(lengths) * MP + 2
+    ks = jax.random.split(key, 3)
+    q = jax.random.normal(ks[0], (4, S, KVH, G, D), jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (P, ps, KVH, D), jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (P, ps, KVH, D), jnp.bfloat16)
+    bt = jnp.asarray(_scattered_tables(np.random.default_rng(3), lengths,
+                                       S, ps, MP, P))
+    out = ops.paged_attention_mq(q, kp, vp, bt, jnp.asarray(lengths),
+                                 block_k=4 * ps)
+    want = ref.paged_attention_mq(q, kp, vp, bt, jnp.asarray(lengths))
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), rtol=2e-2, atol=2e-2)
+
+
+def test_paged_attention_block_k_must_cover_whole_pages(key):
+    """block_k counts tokens of whole pages: one that splits a page is
+    refused rather than silently rounded."""
+    q = jnp.zeros((1, 1, 1, 1, 8), jnp.float32)
+    pages = jnp.zeros((3, 8, 1, 8), jnp.float32)
+    bt = jnp.zeros((1, 2), jnp.int32)
+    with pytest.raises(ValueError, match="multiple of page_size"):
+        ops.paged_attention_mq(q, pages, pages, bt, jnp.ones((1,), jnp.int32),
+                               block_k=4)
 
 
 def test_paged_attention_mq_reduces_to_single_query(key):

@@ -226,7 +226,7 @@ def test_occupancy_scaling_switches_tp_degree_decision():
     assert high.config_for("layer3/attn").tp_degree == 4
 
 
-def _stub_engine(tp_pin=0, n_kv_heads=4, paged=True):
+def _stub_engine(tp_pin=0, n_kv_heads=4, paged=True, head_dim=128):
     """An Engine shell exercising tp_for/_step_cache_key resolution logic
     without a model: only the attributes those methods read are present."""
     from types import SimpleNamespace
@@ -235,7 +235,7 @@ def _stub_engine(tp_pin=0, n_kv_heads=4, paged=True):
     eng = object.__new__(Engine)
     eng.cfg = ServeConfig(tp=tp_pin)
     eng.model = SimpleNamespace(cfg=SimpleNamespace(
-        n_kv_heads=n_kv_heads, n_experts=0))
+        n_kv_heads=n_kv_heads, n_experts=0, resolved_head_dim=head_dim))
     eng._paged = paged
     return eng
 
@@ -288,3 +288,104 @@ def test_step_cache_keys_on_resolved_tp_and_nothing_else(monkeypatch):
     assert eng._step_cache_key(
         plan_of(tp_degree=2, reservation="lazy", mem_watermark=0.3,
                 prefix_cache="on")) == k2
+
+
+def _plan_with_attn(**kw):
+    from repro.core.policy import RegionConfig
+    plan = null_plan()
+    plan.region_configs["layer/attn"] = RegionConfig(**kw)
+    return plan
+
+
+def test_attn_impl_for_resolves_from_backend_and_tp(monkeypatch):
+    """'' (auto) reads the Pallas kernel on a TPU at tp 1 — simulated here
+    without a chip — and the gather on the CPU, at tp 2 or for heads
+    narrower than a lane tile; on the CPU a pin reaches the interpreted
+    kernel at any head width."""
+    import jax
+    monkeypatch.setattr(jax, "devices", lambda: [None] * 2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _stub_engine().attn_impl_for(null_plan()) == "paged"
+    assert _stub_engine().attn_impl_for(_plan_with_attn(tp_degree=2)) == \
+        "gather"
+    assert _stub_engine(tp_pin=2).attn_impl_for(null_plan()) == "gather"
+    # heads the compiled kernel refuses: the gather, over a pin too
+    assert _stub_engine(head_dim=64).attn_impl_for(null_plan()) == "gather"
+    assert _stub_engine(head_dim=64).attn_impl_for(
+        _plan_with_attn(attn_impl="paged")) == "gather"
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert _stub_engine().attn_impl_for(null_plan()) == "gather"
+    assert _stub_engine(head_dim=64).attn_impl_for(
+        _plan_with_attn(attn_impl="paged")) == "paged"
+
+
+def test_attn_impl_explicit_pins_and_safe_plan_win(monkeypatch):
+    import jax
+    monkeypatch.setattr(jax, "devices", lambda: [None] * 1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = _stub_engine()
+    assert eng.attn_impl_for(_plan_with_attn(attn_impl="gather")) == "gather"
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert eng.attn_impl_for(_plan_with_attn(attn_impl="paged")) == "paged"
+    # the safe plan pins the gather explicitly, even over a 'paged' pin
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng.plan = _plan_with_attn(attn_impl="paged")
+    safe = eng._safe_plan()
+    assert safe.config_for("layer0/attn").attn_impl == "gather"
+    assert eng.attn_impl_for(safe) == "gather"
+    eng._force_safe = True
+    assert eng.attn_impl_for(_plan_with_attn(attn_impl="paged")) == "gather"
+
+
+def test_step_cache_keys_on_resolved_attn_impl(monkeypatch):
+    """Plans that resolve to one impl share one step executable; auto
+    resolving differently (another backend) keys another."""
+    import jax
+    monkeypatch.setattr(jax, "devices", lambda: [None] * 1)
+    eng = _stub_engine()
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    auto_cpu = eng._step_cache_key(_plan_with_attn())
+    assert auto_cpu == eng._step_cache_key(_plan_with_attn(attn_impl="gather"))
+    assert auto_cpu != eng._step_cache_key(_plan_with_attn(attn_impl="paged"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    auto_tpu = eng._step_cache_key(_plan_with_attn())
+    assert auto_tpu == eng._step_cache_key(_plan_with_attn(attn_impl="paged"))
+    assert auto_tpu != auto_cpu
+
+
+def test_plan_with_attn_impl_pins_only_unset_knobs():
+    """The lowered plan carries the resolved impl wherever the knob was
+    unset, keeps explicit per-region pins and every other knob."""
+    from repro.core.policy import RegionConfig
+    from repro.serve.engine import Engine
+    plan = null_plan()
+    plan.region_configs["layer"] = RegionConfig(remat=True)
+    plan.region_configs["layer3/attn"] = RegionConfig(attn_impl="gather")
+    pinned = Engine._plan_with_attn_impl(plan, "paged")
+    assert pinned.config_for("layer0/attn").attn_impl == "paged"
+    assert pinned.config_for("layer0/attn").remat is True
+    assert pinned.config_for("layer3/attn").attn_impl == "gather"
+    assert pinned.config_for("layer0/mlp").attn_impl == "paged"
+    assert plan.config_for("layer0/attn").attn_impl == ""   # not mutated
+
+
+def test_refused_heads_lower_the_gather_over_pins(monkeypatch):
+    """On a TPU, heads the compiled kernel refuses lower the gather in
+    every region, over explicit 'paged' pins, and key one executable with
+    the auto plan."""
+    import jax
+    from repro.core.policy import RegionConfig
+    from repro.serve.engine import Engine
+    monkeypatch.setattr(jax, "devices", lambda: [None] * 1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = _stub_engine(head_dim=64)
+    pinned = _plan_with_attn(attn_impl="paged")
+    pinned.region_configs["layer3/attn"] = RegionConfig(attn_impl="paged")
+    assert eng._kernel_refuses_heads()
+    auto = _plan_with_attn()
+    auto.region_configs["layer3/attn"] = RegionConfig()
+    assert eng._step_cache_key(pinned) == eng._step_cache_key(auto)
+    lowered = Engine._plan_with_attn_impl(pinned, "gather", override=True)
+    assert {lowered.config_for(f"layer{i}/attn").attn_impl
+            for i in range(4)} == {"gather"}
+    assert not _stub_engine(head_dim=128)._kernel_refuses_heads()

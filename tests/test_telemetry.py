@@ -461,6 +461,73 @@ def test_step_dispatch_live_tokens_are_the_steps_lengths(profiled):
         assert c["active"] == int(act.sum())
 
 
+def test_kv_pages_read_counts_every_page_on_the_gather_path(profiled):
+    """On the CPU the step resolves to the gather, which reads every
+    block-table page of every active slot."""
+    spans, _, _, calls = profiled
+    got = [s[3] for s in spans if s[0] == "serve.step_dispatch"]
+    max_pages = 5                       # max_len 40 over pages of 8
+    for c, (_, act) in zip(got, calls):
+        assert c["kv_pages_read"] == int(act.sum()) * max_pages
+
+
+def test_kv_pages_read_counts_live_pages_on_the_kernel_path(served,
+                                                            tmp_path,
+                                                            monkeypatch):
+    """With the Pallas kernel pinned (interpreted on the CPU), each step's
+    ``kv_pages_read`` is the sum over active slots of the pages their
+    attention sees: the written tokens plus this step's row.  It is also
+    what every layer's kernel call reads: inactive slots reach the kernel
+    at length 0."""
+    from repro.core.policy import RegionConfig, RegionPlan
+    from repro.kernels import ops
+    from repro.kernels.paged_attention import live_pages
+    model, params, _, _, _, _, _, _, _, _ = served
+    rng = np.random.default_rng(11)
+    read = []
+    kernel = ops.paged_attention_mq
+
+    def counted(q, k_pages, v_pages, bt, lengths, *, block_k=0):
+        jax.debug.callback(lambda n: read.append(int(n)), live_pages(
+            lengths, q.shape[1], k_pages.shape[1]).sum())
+        return kernel(q, k_pages, v_pages, bt, lengths, block_k=block_k)
+
+    monkeypatch.setattr(ops, "paged_attention_mq", counted)
+
+    def mk():
+        return [Request(rid=i, prompt=rng.integers(0, 256, 5 + 9 * i)
+                        .astype(np.int32), max_new_tokens=4 + 3 * i)
+                for i in range(2)]
+
+    plan = RegionPlan(region_configs={
+        "layer/attn": RegionConfig(attn_impl="paged")})
+    eng = Engine(model, params, plan=plan, serve_cfg=ServeConfig(
+        max_len=40, max_slots=2, page_size=8, prefill_chunk=8,
+        spec_depth=0))
+    eng.serve(mk())                                 # compile outside
+    assert eng.observability()["step"]["attn_impl"] == "paged"
+    calls = []
+    step = eng._pool_step
+
+    def kept(params, pages, toks, bt, lengths, act, key):
+        calls.append((np.asarray(lengths), np.asarray(act)))
+        return step(params, pages, toks, bt, lengths, act, key)
+
+    eng._pool_step = kept
+    read.clear()
+    with jax.profiler.trace(str(tmp_path)):
+        res = eng.serve(mk())
+    got = [s[3] for s in _program_spans(tmp_path)
+           if s[0] == "serve.step_dispatch"]
+    assert len(got) == len(calls) == res["steps"]
+    for c, (lengths, act) in zip(got, calls):
+        assert c["kv_pages_read"] == int(
+            live_pages(lengths[act] + 1, 1, 8).sum())
+    assert any(not act.all() for _, act in calls)   # inactive slots ran
+    assert sorted(read) == sorted(c["kv_pages_read"] for c in got
+                                  for _ in range(model.cfg.n_layers))
+
+
 def test_program_times_sit_on_the_trace_clock(profiled):
     """The loop's clock starts at the serve.loop span's start: a request's
     admission time placed on the trace falls inside a serve.admit span,
