@@ -10,13 +10,14 @@ launcher (``repro.launch.serve.main``) with random weights from ``--seed``.
 Phases, all in this one process (it holds the chip and starts no child):
 
 * serve: 8 requests, 256-token prompts, 32-64 new tokens, 64-token prefill
-  chunks, on the paged KV pool under the default (gather) attention plan.
+  chunks, on the paged KV pool under the default plan (on a TPU at tp 1
+  the engine resolves its attention to the Pallas kernel).
 * kernel: the same trace under the ``attn_paged_kernel`` candidate's plan.
-  The step must resolve as the engine says: on a TPU the compiled kernel
-  takes only heads of whole 128-lane tiles, so stablelm-1.6b's 64-wide
-  heads resolve to the gather (no ``tpu_custom_call``) over the pin.  The
+  The step must resolve to the kernel, and on a TPU hold its custom call at
+  stablelm-1.6b's 64-wide heads (the pool's lane-dense page rows, 2,048
+  lanes; rows that are not whole 128-lane tiles resolve to the gather).  The
   kernel must match the ``kernels/ref.py`` oracle at the model's head
-  counts, its head width rounded up to whole lane tiles.
+  counts and width, on pages in the pool's layout.
 * autotune: the same trace with ``--online-retrain``, so measure -> decide
   runs on the counters parsed from the TPU step's HLO.
 * four-chips (only with ``--four-chips``): the trace on the engine at tp=4
@@ -154,22 +155,22 @@ def serve_once(args, ck: Checks, clock: CompileClock, label: str, *extra):
 
 def kernel_oracle(ck: Checks, cfg, page_size: int, on_tpu: bool, seed: int):
     """``paged_attention_mq`` (compiled on TPU) against the f32 oracle at
-    the model's head counts, the head width rounded up to whole 128-lane
-    tiles (what the compiled kernel takes), for plain decode (S=1) and the
-    spec2 verify width (S=3)."""
+    the model's head counts and width, on pages in the pool's layout
+    (:func:`repro.kernels.paged_attention.page_row`), for plain decode
+    (S=1) and the spec2 verify width (S=3)."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops, ref
+    from repro.kernels.paged_attention import page_row
     kvh = cfg.n_kv_heads
     g = cfg.n_heads // kvh
-    hd = -(-cfg.resolved_head_dim // 128) * 128
+    hd = cfg.resolved_head_dim
     B, MP = TRACE["slots"], 32
     n_pages = B * MP + 1
+    pages = (n_pages, page_size) + page_row(kvh, hd)
     kk, kv, kq, kb = jax.random.split(jax.random.PRNGKey(seed), 4)
-    k_pages = jax.random.normal(kk, (n_pages, page_size, kvh, hd),
-                                jnp.bfloat16)
-    v_pages = jax.random.normal(kv, (n_pages, page_size, kvh, hd),
-                                jnp.bfloat16)
+    k_pages = jax.random.normal(kk, pages, jnp.bfloat16)
+    v_pages = jax.random.normal(kv, pages, jnp.bfloat16)
     # each slot owns a scattered set of pages, as the allocator leaves them
     bt = (jax.random.permutation(kb, n_pages - 1) + 1).reshape(B, MP)
     for S in (1, SPEC_DEPTH + 1):
@@ -187,7 +188,7 @@ def kernel_oracle(ck: Checks, cfg, page_size: int, on_tpu: bool, seed: int):
 
 
 def phase_serve(args, ck, clock, on_tpu, state):
-    _, _, state["gather_tokens"] = serve_once(args, ck, clock, "serve")
+    _, _, state["serve_tokens"] = serve_once(args, ck, clock, "serve")
 
 
 def phase_kernel(args, ck, clock, on_tpu, state):
@@ -202,18 +203,18 @@ def phase_kernel(args, ck, clock, on_tpu, state):
             f.write(plan.to_json())
         engine, _, toks = serve_once(args, ck, clock, "kernel",
                                      "--plan", path)
-    from repro.kernels.paged_attention import compiles_head_dim
+    from repro.kernels.paged_attention import compiles_row
     cfg, page_size = engine.model.cfg, engine._pool.page_size
-    want = ("paged" if compiles_head_dim(cfg.resolved_head_dim)
+    want = ("paged" if compiles_row(cfg.n_kv_heads, cfg.resolved_head_dim)
             or not on_tpu else "gather")
     impl = engine.observability()["step"]["attn_impl"]
     ck(f"kernel: the pinned step resolves to {want!r}", impl == want,
-       f"attn_impl={impl!r} head_dim={cfg.resolved_head_dim}")
+       f"attn_impl={impl!r} kv_heads={cfg.n_kv_heads} "
+       f"head_dim={cfg.resolved_head_dim}")
     hlo = engine._pool_step.as_text()
     if on_tpu:
-        ck(f"kernel: compiled step {'holds' if want == 'paged' else 'lacks'}"
-           " the Pallas kernel",
-           ("tpu_custom_call" in hlo) == (want == "paged"),
+        ck("kernel: compiled step holds the Pallas kernel",
+           "tpu_custom_call" in hlo,
            f"tpu_custom_call x{hlo.count('tpu_custom_call')}")
     else:
         print("[check] skipped tpu_custom_call: kernels run in interpret "
@@ -221,9 +222,9 @@ def phase_kernel(args, ck, clock, on_tpu, state):
     del engine
     gc.collect()
     kernel_oracle(ck, cfg, page_size, on_tpu, args.seed)
-    if "gather_tokens" in state:
-        print(f"[smoke reading] greedy token agreement gather vs kernel: "
-              f"{agreement(state['gather_tokens'], toks)!r}", flush=True)
+    if "serve_tokens" in state:
+        print(f"[smoke reading] greedy token agreement default plan vs kernel pin: "
+              f"{agreement(state['serve_tokens'], toks)!r}", flush=True)
 
 
 def phase_autotune(args, ck, clock, on_tpu, state):
@@ -241,10 +242,10 @@ def phase_autotune(args, ck, clock, on_tpu, state):
        at["corpus_entries"] >= 1 and at["retrains"] >= 1,
        f"corpus_entries={at['corpus_entries']} retrains={at['retrains']} "
        f"swaps={at['swaps']}")
-    if "gather_tokens" in state:
+    if "serve_tokens" in state:
         ck("autotune: greedy tokens identical to the serve phase "
-           "(exploration off)", toks == state["gather_tokens"],
-           f"agreement={agreement(state['gather_tokens'], toks)!r}")
+           "(exploration off)", toks == state["serve_tokens"],
+           f"agreement={agreement(state['serve_tokens'], toks)!r}")
 
 
 def _devices_of(tree) -> list[int]:

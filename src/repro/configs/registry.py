@@ -8,6 +8,7 @@ from repro.configs.base import SHAPES, ArchConfig, ShapeConfig
 _MODULES = {
     "qwen3-8b": "repro.configs.qwen3_8b",
     "stablelm-1.6b": "repro.configs.stablelm_1_6b",
+    "stablelm-3b-4e1t": "repro.configs.stablelm_3b_4e1t",
     "h2o-danube-1.8b": "repro.configs.h2o_danube_1_8b",
     "qwen3-32b": "repro.configs.qwen3_32b",
     "internvl2-26b": "repro.configs.internvl2_26b",

@@ -27,10 +27,13 @@ BATCH_AXES = {
 CACHE_AXES = {
     "k": ("batch", "kv_seq", "kv_heads", "head_dim"),
     "v": ("batch", "kv_seq", "kv_heads", "head_dim"),
-    # paged pools: (n_pages, page_size, kv_heads, head_dim).  Page id and
-    # in-page position stay replicated — the host-side block table indexes
-    # them on every shard — so tensor parallelism splits only the kv-head
-    # dim: each shard holds kv_heads/tp heads of EVERY page.
+    # paged pools: (n_pages, page_size, kv_heads, head_dim), or lane-dense
+    # (n_pages, page_size, kv_heads * head_dim), whose last axis takes the
+    # "kv_heads" entry: split into tp contiguous runs of whole heads (the
+    # engine's tp divides kv_heads).  Page id and in-page position stay
+    # replicated — the host-side block table indexes them on every shard —
+    # so tensor parallelism splits only the heads: each shard holds
+    # kv_heads/tp heads of EVERY page.
     "k_pages": (None, None, "kv_heads", "head_dim"),
     "v_pages": (None, None, "kv_heads", "head_dim"),
     "s": ("batch", "ssm_heads", None, None),

@@ -6,12 +6,16 @@ written, several pages per compute block.
 Layout: q is (B, S, KVH, G, HD) — S is the per-slot query length (1 for
 plain decode, ``spec_depth + 1`` for the speculative verify step) and the
 heads are GQA-grouped so K/V are never materialised at the full head count;
-k_pages/v_pages are (P, page_size, KVH, HD) (a page holds every KV head,
-contiguous); the block table is (B, max_pages) int32 page ids (zero-padded
-— page 0 is the pool's null sink) and lengths is (B,) int32: the number of
-KV positions visible to query 0 (each later query sees one more — the
-staircase causal mask of a speculative block whose own K/V rows are
-already written).
+k_pages/v_pages hold one page per leading index, each page row (one token,
+every KV head) laid out by :func:`page_row`: ``(KVH, HD)`` where a head
+fills whole 128-lane tiles, else ``(KVH * HD,)`` lane-dense, so that a row
+of narrow heads is not padded to whole tiles in HBM or VMEM.  Compiled,
+the kernel takes rows of whole lane tiles (:func:`compiles_row`);
+interpreted, any width.  The block table is (B, max_pages) int32 page ids
+(zero-padded — page 0 is the pool's null sink) and lengths is (B,) int32:
+the number of KV positions visible to query 0 (each later query sees one
+more — the staircase causal mask of a speculative block whose own K/V
+rows are already written).
 
 The grid walks the slots; each slot runs a loop over its live compute
 blocks only.  A slot's live pages are ``ceil((lengths + S - 1) /
@@ -23,15 +27,16 @@ first block at a slot's end — start before this block is computed, into
 the other half of a double buffer.  Pages past a slot's length are never
 copied and never computed on; a slot of length 0 does nothing.
 
-Within a block, the page rows (token, kv head) stack into one
-(block_k * KVH, HD) matrix, so the scores of every (query, head) row come
-from one MXU product; a row keeps only its own kv head's columns and the
-positions its query sees.  K and V enter the MXU in the pool's dtype with
-float32 accumulation; scores, the running max, the denominator and the
-accumulator stay float32 (online softmax across the slot's blocks).
-
-Compiled, the kernel takes only heads that fill whole 128-lane tiles
-(:func:`compiles_head_dim`); interpreted, any width runs.
+Every (query, head) row's scores come from one MXU product per block.
+Whole-tile heads: the page rows (token, kv head) stack into one
+(block_k * KVH, HD) matrix and a row keeps only its own kv head's columns.
+Lane-dense rows: the block is one (block_k, KVH * HD) matrix and each
+(query, group, kv head) row of q is zero outside its own head's lanes, so
+the product sums only that head's lanes; P @ V then gives every head's
+lanes and each row keeps its own head's.  K and V enter the MXU in the
+pool's dtype with float32 accumulation; scores, the running max, the
+denominator and the accumulator stay float32 (online softmax across the
+slot's blocks).
 
 Rows whose length is 0 (inactive pool slots) write zeros; the engine masks
 them out at sampling.
@@ -48,13 +53,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_K = 256       # tokens per compute block, picked on a v5e
+LANES = 128                 # the minor dim of a TPU vector tile
+#: The kernel's ``pallas_call`` name: the custom call's kernel name in the
+#: compiled step, which tells its device time apart from the projections
+#: that share its ``R.attn`` scope.
+KERNEL_NAME = "paged_attention_kernel"
 
 
 def shard_kv_heads(kv_heads: int, tp: int) -> int:
     """KV heads each shard sees under a ``tp``-way mesh "model" axis.
 
     Tensor parallelism splits the page pool on the kv-head dim only
-    (pages: ``(P, page_size, KVH/tp, HD)`` per shard) — page ids, in-page
+    (pages: ``(P, page_size, KVH/tp, HD)`` per shard, or ``(P, page_size,
+    KVH/tp * HD)`` lane-dense: whole heads either way) — page ids, in-page
     positions, and the host-side block table are identical on every shard.
     Raises when the head count cannot split evenly — the engine clamps
     requested degrees through this rule before building a sharded step.
@@ -76,28 +87,51 @@ def live_pages(lengths, s_len: int, page_size: int):
     return n * (lengths > 0)
 
 
-def compiles_head_dim(head_dim: int) -> bool:
-    """Whether the compiled (TPU) kernel takes this head width.  A page is
-    copied by slicing the pool's leading dim, and Mosaic slices a ref only
-    when its minor dim fills whole 128-lane tiles: at 64-wide heads the
-    compile for a v5e fails with "Slice shape along dimension 3 must be
-    aligned to tiling (128), but is 64".  Interpreted, any width runs."""
-    return head_dim % 128 == 0
+def page_row(kv_heads: int, head_dim: int) -> tuple:
+    """The shape of one page row (one token, every KV head) in the pool:
+    ``(kv_heads, head_dim)`` where a head fills whole 128-lane tiles, else
+    ``(kv_heads * head_dim,)``.  A minor dim narrower than a lane tile is
+    padded to one in HBM (80-wide heads would take 1.6x their bytes) and
+    cannot be sliced out of the pool by the compiled kernel's page copy."""
+    if head_dim % LANES == 0:
+        return (kv_heads, head_dim)
+    return (kv_heads * head_dim,)
 
 
-def _row_masks(length, rows: int, cols: int, kvh: int, grp: int):
-    """A row (query s, kv head h, group g) keeps the columns (token t, kv
-    head h) of its own head whose position its query sees: the additive
-    head bias (rows, cols), the positions each row sees (rows, 1) and each
-    column's token (1, cols)."""
+def compiles_row(kv_heads: int, head_dim: int) -> bool:
+    """Whether the compiled (TPU) kernel takes pages of these rows.  Mosaic
+    slices a page out of the pool and q's block only when their minor dim
+    fills whole 128-lane tiles: a row of 320 lanes (4 heads of 80) fails
+    the compile for a v5e with "Slice shape along dimension 2 must be
+    aligned to tiling (128), but is 320".  Interpreted, any width runs."""
+    return kv_heads * head_dim % LANES == 0
+
+
+def _row_masks(length, rows: int, cols: int, kvh: int, grp: int,
+               stacked: bool):
+    """The positions each (query, head) row sees (rows, 1) and each
+    column's token (1, cols); stacked rows also keep only their own kv
+    head's columns (token t, kv head h): an additive head bias (rows,
+    cols), else 0.  Rows are query-major either way."""
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+    row_sees = length + row // (kvh * grp)
+    if not stacked:
+        return 0.0, row_sees, col
     head_bias = jnp.where((row // grp) % kvh == col % kvh, 0.0, NEG_INF)
-    return head_bias, length + row // (kvh * grp), col // kvh
+    return head_bias, row_sees, col // kvh
+
+
+def _own_lanes(rows: int, kvh: int, hd: int):
+    """Lane-dense rows (query, group, kv head): each row's own head's
+    lanes of a (rows, KVH * HD) block."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, kvh * hd), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, kvh * hd), 1)
+    return col // hd == row % kvh
 
 
 def _attend(q, k, v, first_tok, masks, scale: float, carry):
-    """One online-softmax step over a (cols, HD) block of stacked page rows
+    """One online-softmax step over a (cols, width) block of page rows
     whose first token is ``first_tok``: K and V enter the MXU in the
     pool's dtype, everything else stays float32."""
     head_bias, row_sees, col_tok = masks
@@ -116,11 +150,13 @@ def _attend(q, k, v, first_tok, masks, scale: float, carry):
 
 def _paged_mq_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                      k_buf, v_buf, sems, state, *,
-                     scale: float, s_len: int, kvh: int, grp: int,
+                     scale: float, s_len: int, kvh: int, grp: int, hd: int,
                      page_size: int, ppb: int, max_pages: int, n_slots: int):
     b = pl.program_id(0)
     bk = ppb * page_size
-    rows, cols = s_len * kvh * grp, bk * kvh
+    stacked = k_buf.ndim == 5           # (2, ppb, page_size, KVH, HD)
+    rows = s_len * kvh * grp
+    cols, width = (bk * kvh, hd) if stacked else (bk, kvh * hd)
 
     def n_pages(slot):
         return jnp.minimum(live_pages(len_ref[slot], s_len, page_size),
@@ -169,8 +205,16 @@ def _paged_mq_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         start(b, 0, state[0])
         state[1] = 1
 
-    masks = _row_masks(len_ref[b], rows, cols, kvh, grp)
-    q = q_ref[0]                                            # (rows, HD)
+    masks = _row_masks(len_ref[b], rows, cols, kvh, grp, stacked)
+    if stacked:
+        q = q_ref[0]                                        # (rows, HD)
+    else:
+        # each (query, group) row of q once per kv head, zero outside
+        # that head's lanes
+        own = _own_lanes(rows, kvh, hd)
+        qs = q_ref[0].astype(jnp.float32)                   # (S*G, width)
+        q = jnp.broadcast_to(qs[:, None, :], (s_len * grp, kvh, width))
+        q = jnp.where(own, q.reshape(rows, width), 0.0).astype(k_buf.dtype)
 
     def body(i, carry):
         cur = state[0]
@@ -195,19 +239,22 @@ def _paged_mq_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                 start(nb, 0, nxt)
 
         wait(b, i, cur)
-        hd = k_buf.shape[-1]
-        carry = _attend(q, k_buf[cur].reshape(cols, hd),
-                        v_buf[cur].reshape(cols, hd), i * bk, masks, scale,
-                        carry)
+        carry = _attend(q, k_buf[cur].reshape(cols, width),
+                        v_buf[cur].reshape(cols, width), i * bk, masks,
+                        scale, carry)
         state[0] = nxt
         return carry
 
-    hd = q_ref.shape[-1]
     m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((rows, 1), jnp.float32)
-    a0 = jnp.zeros((rows, hd), jnp.float32)
+    a0 = jnp.zeros((rows, width), jnp.float32)
     _, l_fin, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
     out = acc / jnp.maximum(l_fin, 1e-30)
+    if not stacked:
+        # each row's own head's lanes, summed over the kv heads of its
+        # (query, group): one nonzero term per lane
+        out = jnp.where(own, out, 0.0).reshape(s_len * grp, kvh, width)
+        out = jnp.sum(out, axis=1)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -215,7 +262,8 @@ def _paged_mq_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 def paged_attention_mq(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                        block_tables: jax.Array, lengths: jax.Array, *,
                        block_k: int = 0, interpret: bool = False) -> jax.Array:
-    """q: (B, S, KVH, G, HD); pages: (P, page_size, KVH, HD) -> same as q.
+    """q: (B, S, KVH, G, HD); pages: (P, page_size) + :func:`page_row`
+    -> same as q.
 
     ``block_tables``: (B, max_pages) int32; ``lengths``: (B,) int32 KV
     positions visible to query 0 (query ``s`` sees ``lengths + s`` — the
@@ -224,25 +272,33 @@ def paged_attention_mq(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     default, :data:`DEFAULT_BLOCK_K`, cut to the block table's reach).
     """
     B, s_len, kvh, g, hd = q.shape
-    _, page_size, kvh_p, hd_p = k_pages.shape
-    assert (kvh_p, hd_p) == (kvh, hd), "page layout mismatch"
+    page_size = k_pages.shape[1]
+    row = k_pages.shape[2:]
+    if row != page_row(kvh, hd):
+        raise ValueError(f"pages of rows {row} do not hold {kvh} heads of "
+                         f"{hd} in the pool's layout {page_row(kvh, hd)}")
+    if not (interpret or compiles_row(kvh, hd)):
+        raise ValueError(f"rows of {kvh * hd} lanes are not whole "
+                         f"{LANES}-lane tiles: the compiled kernel cannot "
+                         f"copy their pages")
     max_pages = block_tables.shape[1]
     if block_k % page_size:
         raise ValueError(f"block_k={block_k} is not a multiple of "
                          f"page_size={page_size}")
-    if not interpret and not compiles_head_dim(hd):
-        raise ValueError(f"head_dim={hd} does not fill whole 128-lane "
-                         f"tiles: the compiled kernel cannot copy its pages")
     ppb = block_k // page_size or min(
         max(DEFAULT_BLOCK_K // page_size, 1), max_pages)
-    rows = s_len * kvh * g
+    if len(row) == 2:
+        q_rows = q.reshape(B, s_len * kvh * g, hd)
+    else:
+        # rows (query, group), lanes (kv head, dim): a page row's order
+        q_rows = q.transpose(0, 1, 3, 2, 4).reshape(B, s_len * g, kvh * hd)
 
     scale = 1.0 / math.sqrt(hd)
-    q_spec = pl.BlockSpec((1, rows, hd), lambda b, *_: (b, 0, 0))
+    q_spec = pl.BlockSpec((1,) + q_rows.shape[1:], lambda b, *_: (b, 0, 0))
     kern = functools.partial(
-        _paged_mq_kernel, scale=scale, s_len=s_len, kvh=kvh, grp=g,
+        _paged_mq_kernel, scale=scale, s_len=s_len, kvh=kvh, grp=g, hd=hd,
         page_size=page_size, ppb=ppb, max_pages=max_pages, n_slots=B)
-    buf = pltpu.VMEM((2, ppb, page_size, kvh, hd), k_pages.dtype)
+    buf = pltpu.VMEM((2, ppb, page_size) + row, k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(B,),
         in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
@@ -253,13 +309,16 @@ def paged_attention_mq(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, rows, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_rows.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(block_tables.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
-      q.reshape(B, rows, hd), k_pages, v_pages)
-    return out.reshape(B, s_len, kvh, g, hd)
+      q_rows, k_pages, v_pages)
+    if len(row) == 2:
+        return out.reshape(B, s_len, kvh, g, hd)
+    return out.reshape(B, s_len, g, kvh, hd).transpose(0, 1, 3, 2, 4)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))

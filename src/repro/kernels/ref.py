@@ -30,8 +30,9 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
 
 def paged_attention_mq(q, k_pages, v_pages, block_tables, lengths):
     """Multi-query paged decode oracle (the speculative verify step's
-    attention).  q: (B, S, KVH, G, HD); pages: (P, ps, KVH, HD);
-    block_tables: (B, MP) int32; lengths: (B,) int32 -> same shape as q.
+    attention).  q: (B, S, KVH, G, HD); pages: (P, ps, KVH, HD) or
+    lane-dense (P, ps, KVH * HD); block_tables: (B, MP) int32; lengths:
+    (B,) int32 -> same shape as q.
 
     Gathers every sequence's pages dense and runs grouped-GQA softmax
     attention with the staircase mask: query ``s`` sees ``lengths + s``
@@ -40,7 +41,7 @@ def paged_attention_mq(q, k_pages, v_pages, block_tables, lengths):
     """
     B, S, KVH, G, D = q.shape
     ps = k_pages.shape[1]
-    k = k_pages[block_tables]                  # (B, MP, ps, KVH, HD)
+    k = k_pages[block_tables]                  # (B, MP, ps) + page row
     v = v_pages[block_tables]
     T = k.shape[1] * ps
     k = k.reshape(B, T, KVH, D)

@@ -270,23 +270,29 @@ def _attention_decode_block(cfg, p, x, cache, pos, plan: RegionPlan,
 
 def paged_kv_spec(cfg, n_pages: int, page_size: int, dtype=jnp.bfloat16):
     """Page-pool shapes for one attention instance: a global block pool
-    instead of per-request whole caches."""
-    kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    instead of per-request whole caches.  A page row (one token, every KV
+    head) is laid out as the paged kernel reads it
+    (:func:`repro.kernels.paged_attention.page_row`): ``(KV, HD)`` for
+    heads of whole 128-lane tiles, ``(KV * HD,)`` lane-dense otherwise."""
+    from repro.kernels.paged_attention import page_row
+    shape = (n_pages, page_size) + page_row(cfg.n_kv_heads,
+                                            cfg.resolved_head_dim)
     return {
-        "k_pages": jax.ShapeDtypeStruct((n_pages, page_size, kv, hd), dtype),
-        "v_pages": jax.ShapeDtypeStruct((n_pages, page_size, kv, hd), dtype),
+        "k_pages": jax.ShapeDtypeStruct(shape, dtype),
+        "v_pages": jax.ShapeDtypeStruct(shape, dtype),
     }
 
 
 def _paged_write(pages, new, block_tables, offsets):
     """Scatter per-token K or V rows into the page pool.
 
-    pages: (P, ps, KV, HD); new: (N, KV, HD); block_tables: (N, MP) — the
-    owning slot's block-table row per written token; offsets: (N,) absolute
-    token offsets within each token's sequence.  Live slots never share
-    pages (allocator invariant); slots parked on the all-zero block table,
-    and offsets beyond the block table's reach (a padded final prefill
-    chunk overhanging max_len), are routed explicitly to page 0 — the sink.
+    pages: (P, ps) + page row; new: (N, KV, HD); block_tables: (N, MP) —
+    the owning slot's block-table row per written token; offsets: (N,)
+    absolute token offsets within each token's sequence.  Live slots never
+    share pages (allocator invariant); slots parked on the all-zero block
+    table, and offsets beyond the block table's reach (a padded final
+    prefill chunk overhanging max_len), are routed explicitly to page 0 —
+    the sink.
     """
     ps = pages.shape[1]
     mp = block_tables.shape[1]
@@ -297,7 +303,8 @@ def _paged_write(pages, new, block_tables, offsets):
                                    axis=1)[:, 0]
     page_ids = jnp.where(in_range, page_ids, 0)
     slot_off = jnp.where(in_range, offsets % ps, 0)
-    return pages.at[page_ids, slot_off].set(new.astype(pages.dtype))
+    rows = new.reshape(new.shape[:1] + pages.shape[2:])
+    return pages.at[page_ids, slot_off].set(rows.astype(pages.dtype))
 
 
 def _qkv_rope(cfg, p, x, positions):
@@ -314,10 +321,9 @@ def _qkv_rope(cfg, p, x, positions):
     return q, k_new, v_new
 
 
-def _paged_gather(pages, block_table):
-    """(P, ps, KV, HD) gathered through (..., MP) -> (..., MP*ps, KV, HD)."""
-    g = pages[block_table]
-    return g.reshape(g.shape[:-4] + (g.shape[-4] * g.shape[-3],) + g.shape[-2:])
+def _paged_gather(pages, block_table, kvh: int, hd: int):
+    """(P, ps) + page row gathered through (..., MP) -> (..., MP*ps, KV, HD)."""
+    return pages[block_table].reshape(block_table.shape[:-1] + (-1, kvh, hd))
 
 
 def apply_attention_paged_decode(cfg, p, x, pages, block_tables, lengths,
@@ -328,11 +334,12 @@ def apply_attention_paged_decode(cfg, p, x, pages, block_tables, lengths,
     verify step scoring a drafted block in one pass).
 
     x: (B, S, D) — B is the slot axis; pages: {"k_pages","v_pages"}:
-    (P, ps, KV, HD); block_tables: (B, MP) int32; lengths: (B,) int32
-    tokens already written per slot.  Token i of a slot lands at offset
-    ``lengths[b] + i`` and its query attends causally up to and including
-    its own row (the staircase mask), so slots carry independent positions
-    natively — no vmap over single-request caches.  Rejected speculative
+    (P, ps) + page row (:func:`paged_kv_spec`); block_tables: (B, MP)
+    int32; lengths: (B,) int32 tokens already written per slot.  Token i
+    of a slot lands at offset ``lengths[b] + i`` and its query attends
+    causally up to and including its own row (the staircase mask), so
+    slots carry independent positions natively — no vmap over
+    single-request caches.  Rejected speculative
     rows are rolled back host-side (lengths truncate; the rows are
     overwritten by the next step's writes before any mask admits them).
 
@@ -378,8 +385,8 @@ def apply_attention_paged_decode(cfg, p, x, pages, block_tables, lengths,
             attn = attn.astype(x.dtype)
         elif S == 1:
             qg = q.reshape(B, kvh, grp, hd)
-            k = _paged_gather(k_pages, block_tables)        # (B, T, KV, HD)
-            v = _paged_gather(v_pages, block_tables)
+            k = _paged_gather(k_pages, block_tables, kvh, hd)   # (B,T,KV,HD)
+            v = _paged_gather(v_pages, block_tables, kvh, hd)
             T = k.shape[1]
             # valid: every written position, including this step's token
             valid = jnp.arange(T, dtype=jnp.int32)[None, :] <= lengths[:, None]
@@ -392,8 +399,8 @@ def apply_attention_paged_decode(cfg, p, x, pages, block_tables, lengths,
             attn = jnp.einsum("bhgk,bkhe->bhge", probs, v)
         else:
             qg = q.reshape(B, S, kvh, grp, hd)
-            k = _paged_gather(k_pages, block_tables)        # (B, T, KV, HD)
-            v = _paged_gather(v_pages, block_tables)
+            k = _paged_gather(k_pages, block_tables, kvh, hd)   # (B,T,KV,HD)
+            v = _paged_gather(v_pages, block_tables, kvh, hd)
             T = k.shape[1]
             # staircase: query i sees every written position through its own
             valid = (jnp.arange(T, dtype=jnp.int32)[None, None, :]
@@ -435,11 +442,11 @@ def apply_attention_paged_chunk(cfg, p, x, pages, block_table, base,
         v_pages = _paged_write(pages["v_pages"], v_new[0], bt_rows, positions)
         new_pages = {"k_pages": k_pages, "v_pages": v_pages}
 
-        k = _paged_gather(k_pages, block_table[None, :])    # (1, T, KV, HD)
-        v = _paged_gather(v_pages, block_table[None, :])
-        T = k.shape[1]
         hd = q.shape[-1]
         kvh, grp = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+        k = _paged_gather(k_pages, block_table[None, :], kvh, hd)  # (1,T,KV,HD)
+        v = _paged_gather(v_pages, block_table[None, :], kvh, hd)
+        T = k.shape[1]
         qg = q.reshape(1, C, kvh, grp, hd)
         s = jnp.einsum("bqhge,bkhe->bhgqk", qg, k) / math.sqrt(hd)
         s = plan.constrain(s, rpath,

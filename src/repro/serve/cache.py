@@ -4,11 +4,13 @@ Two pool layouts back :meth:`repro.serve.engine.Engine.serve`:
 
 * :class:`PagedKVPool` — the default for full-KV attention families.  KV
   memory is ONE global block pool per layer: ``k_pages``/``v_pages`` of
-  shape ``(n_pages, page_size, KV, HD)``.  A request maps only the pages
-  its sequence actually occupies, recorded in a per-slot *block table*
-  (``(n_slots, max_pages_per_slot)`` int32 page ids, zero-padded).  Token
-  ``t`` of a slot lives at ``(block_table[t // page_size], t % page_size)``.
-  Page 0 is a reserved *null sink*: the allocator never hands it out, freed
+  shape ``(n_pages, page_size, KV, HD)``, or ``(n_pages, page_size, KV *
+  HD)`` for heads that do not fill whole 128-lane tiles (lane-dense rows:
+  :func:`repro.kernels.paged_attention.page_row`).  A request maps only
+  the pages its sequence actually occupies, recorded in a per-slot *block
+  table* (``(n_slots, max_pages_per_slot)`` int32 page ids, zero-padded).
+  Token ``t`` of a slot lives at ``(block_table[t // page_size], t %
+  page_size)``.  Page 0 is a reserved *null sink*: the allocator never hands it out, freed
   slots have all-zero block tables, so fixed-shape decode writes for
   inactive slots land harmlessly in page 0 instead of corrupting a live
   page.  Admission has two modes, chosen by the
@@ -809,6 +811,13 @@ class PagedKVPool:
         per = [int(np.prod(l.shape[1:])) * l.dtype.itemsize
                for l in jax.tree.leaves(self.pages)]
         return int(sum(per))
+
+    @property
+    def layout(self) -> str:
+        """How a page row lies in the pool: 'lane_dense' (one ``KV * HD``
+        row a token) or 'per_head' (``KV`` rows of ``HD``)."""
+        leaf = jax.tree.leaves(self.pages)[0]
+        return "lane_dense" if leaf.ndim == 3 else "per_head"
 
     def hbm_bytes(self) -> int:
         """Total pool HBM footprint (all pages, live or free)."""

@@ -108,7 +108,7 @@ from repro.autotune.candidates import canonical
 from repro.autotune.decider import PlanDecider  # noqa: F401  (re-export:
                                                 # moved to repro.autotune)
 from repro.core.policy import RegionConfig, RegionPlan, null_plan
-from repro.kernels.paged_attention import compiles_head_dim, live_pages
+from repro.kernels.paged_attention import compiles_row, live_pages
 from repro.models.model import Model
 from repro.serve.faults import FaultInjector
 from repro.serve.health import HealthMonitor, HealthPolicy
@@ -715,9 +715,9 @@ class Engine:
         on the CPU the kernel would run interpreted, and at tp > 1 GSPMD
         cannot split the kernel's custom call over the kv-head axis.  A
         degraded engine (``_force_safe``) pins 'gather' ahead of
-        everything, and so do heads the compiled kernel refuses
-        (:meth:`_kernel_refuses_heads`), over a 'paged' pin too."""
-        if self._force_safe or self._kernel_refuses_heads():
+        everything, and so do page rows the compiled kernel refuses
+        (:meth:`_kernel_refuses_rows`), over a 'paged' pin too."""
+        if self._force_safe or self._kernel_refuses_rows():
             return "gather"
         impl = plan.config_for("layer0/attn").attn_impl
         if impl in ("gather", "paged"):
@@ -726,12 +726,15 @@ class Engine:
             return "paged"
         return "gather"
 
-    def _kernel_refuses_heads(self) -> bool:
-        """On a TPU the compiled kernel takes only heads of whole 128-lane
-        tiles (:func:`compiles_head_dim`); the gather serves the rest,
-        whatever the plan pins."""
-        return (jax.default_backend() == "tpu" and not compiles_head_dim(
-            self.model.cfg.resolved_head_dim))
+    def _kernel_refuses_rows(self) -> bool:
+        """On a TPU the compiled kernel takes only page rows (every KV head
+        of a token, lane-dense) of whole 128-lane tiles
+        (:func:`compiles_row`); the gather serves the rest, whatever the
+        plan pins.  stablelm-1.6b's 32 x 64, stablelm-3b-4e1t's 32 x 80
+        and qwen3-8b's 8 x 128 fill them; the reduced configs do not."""
+        cfg = self.model.cfg
+        return (jax.default_backend() == "tpu" and not compiles_row(
+            cfg.n_kv_heads, cfg.resolved_head_dim))
 
     @staticmethod
     def _plan_with_attn_impl(plan: RegionPlan, impl: str,
@@ -740,7 +743,7 @@ class Engine:
         resolved impl — the attention region's effective config included
         — so '' never reaches the model code (mirrors
         :meth:`_plan_with_scan_mode`).  ``override``: explicit pins are
-        replaced too (heads the compiled kernel refuses)."""
+        replaced too (rows the compiled kernel refuses)."""
         import copy
         plan2 = copy.deepcopy(plan)
         rcs = plan2.region_configs
@@ -978,7 +981,7 @@ class Engine:
         impl = self.attn_impl_for(plan)
         self._apply_tp(tp)          # lowering captures the live placement
         splan = self._serve_plan(self._plan_with_attn_impl(
-            plan, impl, override=self._kernel_refuses_heads()), tp)
+            plan, impl, override=self._kernel_refuses_rows()), tp)
         mesh = splan.mesh if tp > 1 else None
         step = paged_step_fn(self.model, splan, self.cfg.temperature, mesh)
 
@@ -1328,7 +1331,7 @@ class Engine:
             # attn_impl keys on its RESOLVED value: '' and the impl it
             # resolves to share one executable
             impl = self.attn_impl_for(plan)
-            refused = self._kernel_refuses_heads()
+            refused = self._kernel_refuses_rows()
             for rc in raw.get("regions", {}).values():
                 rc["attn_impl"] = (not refused and rc.get("attn_impl")
                                    or impl)
@@ -1472,7 +1475,8 @@ class Engine:
         if self._paged and self.governor is not None:
             pool = self._pool
             obs["memory"] = self.governor.summary()
-            obs["step"] = {"attn_impl": self._attn_impl}
+            obs["step"] = {"attn_impl": self._attn_impl,
+                           "pool_layout": pool.layout}
             # mesh placement: page bytes are per DEVICE (pages shard on
             # kv_heads, so each device holds 1/tp of every page);
             # page/watermark COUNTS are tp-invariant
@@ -2217,9 +2221,10 @@ class Engine:
                                               pool.page_size).sum())
                     else:
                         read = int(eff.sum()) * pool.max_pages_per_slot
-                    ph.set_metadata(active=int(eff.sum()),
-                                    live_tokens=int(lengths.sum()),
-                                    kv_pages_read=read)
+                    ph.set_metadata(
+                        active=int(eff.sum()), live_tokens=int(lengths.sum()),
+                        kv_pages_read=read,
+                        kv_bytes_read=read * pool.per_device_page_bytes())
             if (self.faults is not None
                     and self.faults.fire("step.latency")):
                 # artificial latency spike, inside the step's measured

@@ -91,6 +91,7 @@ def test_param_counts_match_published():
         "qwen3-8b": (8.0e9, 8.4e9),
         "qwen3-32b": (32e9, 33.5e9),
         "stablelm-1.6b": (1.5e9, 1.8e9),
+        "stablelm-3b-4e1t": (2.7e9, 2.9e9),    # published 2.795e9
         "h2o-danube-1.8b": (1.7e9, 1.9e9),
         "rwkv6-3b": (2.9e9, 3.2e9),
         "whisper-large-v3": (1.5e9, 1.7e9),

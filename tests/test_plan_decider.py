@@ -5,6 +5,7 @@ The serve-time loop is: measured region counters, scaled by the pool's
 occupancy fraction, featurised, classified by the tuner-trained tree, the
 predicted candidate overlaid on the plan.  These tests pin each stage."""
 import numpy as np
+import pytest
 
 from repro.core.counters import Counters
 from repro.core.dtree import DecisionTree, features
@@ -297,25 +298,33 @@ def _plan_with_attn(**kw):
     return plan
 
 
+# (kv heads, head width) of stablelm-1.6b, stablelm-3b-4e1t and qwen3-8b:
+# page rows of 2,048, 2,560 and 1,024 lanes, whole 128-lane tiles
+PUBLISHED_HEADS = {64: 32, 80: 32, 128: 8}
+
+
 def test_attn_impl_for_resolves_from_backend_and_tp(monkeypatch):
     """'' (auto) reads the Pallas kernel on a TPU at tp 1 — simulated here
-    without a chip — and the gather on the CPU, at tp 2 or for heads
-    narrower than a lane tile; on the CPU a pin reaches the interpreted
-    kernel at any head width."""
+    without a chip — for 64-, 80- and 128-wide heads at their published
+    counts (page rows of whole lane tiles), and the gather on the CPU, at
+    tp 2, or for rows off the lane tiles (4 heads of 80), over a pin too;
+    on the CPU a pin reaches the interpreted kernel at any width."""
     import jax
     monkeypatch.setattr(jax, "devices", lambda: [None] * 2)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert _stub_engine().attn_impl_for(null_plan()) == "paged"
+    for hd, kvh in PUBLISHED_HEADS.items():
+        eng = _stub_engine(n_kv_heads=kvh, head_dim=hd)
+        assert eng.attn_impl_for(null_plan()) == "paged"
+        assert eng.attn_impl_for(_plan_with_attn(attn_impl="paged")) == \
+            "paged"
+    assert _stub_engine(head_dim=80).attn_impl_for(
+        _plan_with_attn(attn_impl="paged")) == "gather"
     assert _stub_engine().attn_impl_for(_plan_with_attn(tp_degree=2)) == \
         "gather"
     assert _stub_engine(tp_pin=2).attn_impl_for(null_plan()) == "gather"
-    # heads the compiled kernel refuses: the gather, over a pin too
-    assert _stub_engine(head_dim=64).attn_impl_for(null_plan()) == "gather"
-    assert _stub_engine(head_dim=64).attn_impl_for(
-        _plan_with_attn(attn_impl="paged")) == "gather"
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert _stub_engine().attn_impl_for(null_plan()) == "gather"
-    assert _stub_engine(head_dim=64).attn_impl_for(
+    assert _stub_engine(head_dim=80).attn_impl_for(
         _plan_with_attn(attn_impl="paged")) == "paged"
 
 
@@ -369,23 +378,45 @@ def test_plan_with_attn_impl_pins_only_unset_knobs():
     assert plan.config_for("layer0/attn").attn_impl == ""   # not mutated
 
 
-def test_refused_heads_lower_the_gather_over_pins(monkeypatch):
-    """On a TPU, heads the compiled kernel refuses lower the gather in
-    every region, over explicit 'paged' pins, and key one executable with
-    the auto plan."""
+@pytest.mark.parametrize("head_dim", [64, 80, 128])
+def test_every_head_width_keeps_its_pins(monkeypatch, head_dim):
+    """On a TPU every head width at its published count keys a 'paged'
+    pin and the auto plan to one executable, and lowering pins the
+    resolved impl only where the knob was unset: an explicit 'gather' pin
+    stays."""
     import jax
     from repro.core.policy import RegionConfig
     from repro.serve.engine import Engine
     monkeypatch.setattr(jax, "devices", lambda: [None] * 1)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    eng = _stub_engine(head_dim=64)
+    eng = _stub_engine(n_kv_heads=PUBLISHED_HEADS[head_dim],
+                       head_dim=head_dim)
+    assert eng._step_cache_key(_plan_with_attn(attn_impl="paged")) == \
+        eng._step_cache_key(_plan_with_attn())
+    plan = _plan_with_attn()
+    plan.region_configs["layer3/attn"] = RegionConfig(attn_impl="gather")
+    lowered = Engine._plan_with_attn_impl(plan, eng.attn_impl_for(plan))
+    assert [lowered.config_for(f"layer{i}/attn").attn_impl
+            for i in range(4)] == ["paged", "paged", "paged", "gather"]
+
+
+def test_refused_rows_lower_the_gather_over_pins(monkeypatch):
+    """On a TPU, page rows the compiled kernel refuses (4 kv heads of 80:
+    320 lanes) lower the gather in every region, over explicit 'paged'
+    pins, and key one executable with the auto plan."""
+    import jax
+    from repro.core.policy import RegionConfig
+    from repro.serve.engine import Engine
+    monkeypatch.setattr(jax, "devices", lambda: [None] * 1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = _stub_engine(n_kv_heads=4, head_dim=80)
     pinned = _plan_with_attn(attn_impl="paged")
     pinned.region_configs["layer3/attn"] = RegionConfig(attn_impl="paged")
-    assert eng._kernel_refuses_heads()
+    assert eng._kernel_refuses_rows()
     auto = _plan_with_attn()
     auto.region_configs["layer3/attn"] = RegionConfig()
     assert eng._step_cache_key(pinned) == eng._step_cache_key(auto)
     lowered = Engine._plan_with_attn_impl(pinned, "gather", override=True)
     assert {lowered.config_for(f"layer{i}/attn").attn_impl
             for i in range(4)} == {"gather"}
-    assert not _stub_engine(head_dim=128)._kernel_refuses_heads()
+    assert not _stub_engine(n_kv_heads=8, head_dim=128)._kernel_refuses_rows()

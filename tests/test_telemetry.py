@@ -461,14 +461,27 @@ def test_step_dispatch_live_tokens_are_the_steps_lengths(profiled):
         assert c["active"] == int(act.sum())
 
 
-def test_kv_pages_read_counts_every_page_on_the_gather_path(profiled):
+def _page_bytes(model, dtype) -> int:
+    """K and V bytes of one page of 8 tokens over every layer, in the
+    pool's layout."""
+    spec = model.paged_cache_spec(1, 8, dtype=dtype)
+    return sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+               for a in jax.tree.leaves(spec))
+
+
+def test_kv_pages_read_counts_every_page_on_the_gather_path(profiled, served):
     """On the CPU the step resolves to the gather, which reads every
-    block-table page of every active slot."""
+    block-table page of every active slot; ``kv_bytes_read`` is those
+    pages' bytes."""
     spans, _, _, calls = profiled
+    model, params = served[:2]
+    dtype = jax.tree.leaves(params)[0].dtype
     got = [s[3] for s in spans if s[0] == "serve.step_dispatch"]
     max_pages = 5                       # max_len 40 over pages of 8
     for c, (_, act) in zip(got, calls):
         assert c["kv_pages_read"] == int(act.sum()) * max_pages
+        assert c["kv_bytes_read"] == c["kv_pages_read"] * _page_bytes(
+            model, dtype)
 
 
 def test_kv_pages_read_counts_live_pages_on_the_kernel_path(served,
@@ -523,6 +536,8 @@ def test_kv_pages_read_counts_live_pages_on_the_kernel_path(served,
     for c, (lengths, act) in zip(got, calls):
         assert c["kv_pages_read"] == int(
             live_pages(lengths[act] + 1, 1, 8).sum())
+        assert c["kv_bytes_read"] == (c["kv_pages_read"]
+                                      * eng._pool.page_bytes())
     assert any(not act.all() for _, act in calls)   # inactive slots ran
     assert sorted(read) == sorted(c["kv_pages_read"] for c in got
                                   for _ in range(model.cfg.n_layers))
