@@ -332,6 +332,31 @@ def paged_step_fn(model: Model, plan: RegionPlan, temperature: float,
     return step
 
 
+@jax.jit
+def _carry_tokens(prev, host, carry):
+    """The next pool step's input tokens ``(B, 1)``: where ``carry``, the
+    token the step in flight sampled (``prev[:, :1]``, never read back to
+    the host); elsewhere the host's pending token."""
+    return jnp.where(carry[:, None], prev[:, :1], host)
+
+
+@dataclasses.dataclass
+class _Step:
+    """A dispatched pool decode step whose tokens the host has not read."""
+    index: int                      # 1-based step number
+    out: Any                        # (B, S) sampled tokens, on the device
+    finite: Any                     # (B,) health flags, on the device
+    toks_in: np.ndarray             # (B, S) pending token + drafts (host)
+    depth: int                      # spec depth D it ran at
+    rows: dict                      # slot -> Request stepped
+    written: dict                   # slot -> rows advanced at dispatch
+    n_act: int                      # decode residents (the tap's load)
+    t_start: float                  # perf_counter before its headroom
+    # slots whose row was voided before the wait: their previous step
+    # faulted, so the token this row consumed was garbage
+    dropped: set = dataclasses.field(default_factory=set)
+
+
 class Engine:
     # class-level defaults so resolution helpers (tp_for/spec_depth_for)
     # stay callable on partially-constructed engine shells (tests stub
@@ -1089,24 +1114,28 @@ class Engine:
                    jnp.asarray(true_len, jnp.int32))
         return cache, int(prompt[-1])
 
+    def _replan_due(self, n_active: int) -> bool:
+        """Whether :meth:`_maybe_replan` would re-decide now."""
+        if self._pool_rc is None or self.decider is None:
+            return False
+        if self._fallback is not None:
+            # degraded: the safe plan is pinned; a replan would override
+            # it.  Recovery (_exit_fallback) sets _force_replan so the
+            # decider re-decides promptly once healthy.
+            return False
+        return (load_bucket(n_active) != self._load_bucket
+                or self.decider.version != self._decider_version
+                or self._force_replan)
+
     def _maybe_replan(self, n_active: int):
         """On load-bucket changes — or when the decider's tree was hot-
         swapped (version bump) or the explorer forced a re-decide — re-pick
         the decode plan via the dtree.  Without the version check a freshly
         retrained tree would silently never take effect until the next
         occupancy-bucket change (regression-tested)."""
-        if self._pool_rc is None or self.decider is None:
-            return
-        if self._fallback is not None:
-            # degraded: the safe plan is pinned; a replan would override
-            # it.  Recovery (_exit_fallback) sets _force_replan so the
-            # decider re-decides promptly once healthy.
+        if not self._replan_due(n_active):
             return
         bucket = load_bucket(n_active)
-        if (bucket == self._load_bucket
-                and self.decider.version == self._decider_version
-                and not self._force_replan):
-            return
         self._load_bucket = bucket
         self._decider_version = self.decider.version
         self._force_replan = False
@@ -1566,6 +1595,18 @@ class Engine:
         self._pool_step, self._spec_depth, self._attn_impl = step, depth, impl
         self._force_replan = True
 
+    def _fallback_due(self) -> bool:
+        """Whether the health ladder asks to enter or leave the fallback."""
+        return self.health.degraded != (self._fallback is not None)
+
+    def _apply_health(self):
+        """Enter the safe-plan fallback while degraded, restore on
+        recovery."""
+        if self.health.degraded:
+            self._enter_fallback()
+        else:
+            self._exit_fallback()
+
     def _commit_tokens(self, sched: Scheduler, out_np, n_cand, pending,
                        active, t, on_complete) -> dict:
         """Shared post-step bookkeeping for both pools: walk each active
@@ -1866,6 +1907,23 @@ class Engine:
         plain step yields); the rejected tail is rolled back by pure
         length truncation — no page churn, greedy tokens bit-identical to
         the non-speculative path.
+
+        **One step in flight** (spec depth 0): each pass dispatches its
+        step before it waits on the step the previous pass dispatched,
+        so the host's work of a pass (that step's commit, admission, a
+        prefill chunk, headroom, the next inputs) runs while the device
+        computes.  The next step needs none of step k's tokens on the
+        host: a slot step k stepped takes the token step k sampled, on
+        the device (:func:`_carry_tokens`); lengths advance at dispatch;
+        a request whose budget step k fills sits the next step out.  A
+        row of the next step whose request step k ended (EOS, failure),
+        whose token step k faulted, or whose slot was preempted commits
+        nothing, its advance undone like a stall's.  The loop waits on
+        the step in flight first (a drain: the serial order) where the
+        next step cannot be predicted or its program may change —
+        speculation, a re-plan, the fallback — and where nothing is left
+        to step.  ``overlap`` in the return counts ``steps_ahead``,
+        ``drains`` and ``dropped_rows``.
         """
         pool = self._pool
         gov = self.governor
@@ -1880,7 +1938,11 @@ class Engine:
         committed_total = 0                 # tokens committed by decode steps
         slot_steps = 0                      # sum of stepped slots over steps
         max_depth = 0                       # deepest speculation actually run
-        prev_stall: set = set()             # last step's stalled slot set
+        prev_eff: set = set()               # last step's stepped slot set
+        fl: Optional[_Step] = None          # the step in flight, unread
+        overlap = {"steps_ahead": 0, "drains": 0, "dropped_rows": 0}
+        pass_tokens: Optional[int] = None   # committed this pass (if any)
+        t_done_prev = float("-inf")         # the last step's completion
         # the DECODE-masked block tables change only when pool composition
         # changes (admission / completion / preemption / stall), not every
         # step — cache the device array instead of re-uploading it per step
@@ -2008,14 +2070,183 @@ class Engine:
                 else:
                     prefills.append(req)
 
+        def dispatch(stepped, n_act, D, prev, t_start) -> _Step:
+            """Build the step's inputs, call the pool step and advance each
+            stepped slot by the rows it writes.  Reads nothing back from
+            the device: a slot that ``prev`` (the step still in flight)
+            stepped takes the token ``prev`` sampled, on the device."""
+            nonlocal key, steps, max_depth, prev_eff
+            S = D + 1
+            with span("serve.step_dispatch") as ph:
+                max_depth = max(max_depth, D)
+                toks_in = np.zeros((B, S), np.int32)
+                toks_in[:, 0] = pending
+                if D:
+                    for slot in stepped:
+                        toks_in[slot, 1:] = draft_ngram(
+                            sched.active[slot].token_history(), D)
+                key, sub = jax.random.split(key)
+                # expose only the stepped DECODE slots (null page
+                # otherwise); a stalled slot keeps its pending token and
+                # state untouched and simply retries next step
+                eff = np.zeros((B,), bool)
+                eff[stepped] = True
+                if set(stepped) != prev_eff:
+                    prev_eff = set(stepped)
+                    bt_dev["dirty"] = True
+                if bt_dev["dirty"]:
+                    bt_dev["arr"] = jnp.asarray(
+                        pool.block_tables * eff[:, None])
+                    bt_dev["act"] = jnp.asarray(eff)
+                    bt_dev["dirty"] = False
+                lengths = pool.lengths * eff
+                tokens = jnp.asarray(toks_in)
+                if prev is not None:
+                    carry = np.zeros((B,), bool)
+                    for slot in stepped:
+                        carry[slot] = (prev.rows.get(slot) is sched.active[slot]
+                                       and slot not in prev.dropped)
+                    if carry.any():
+                        tokens = _carry_tokens(prev.out, tokens,
+                                               jnp.asarray(carry))
+                out, finite, pool.pages = self._pool_step(
+                    self._step_params, pool.pages, tokens,
+                    bt_dev["arr"], jnp.asarray(lengths), bt_dev["act"], sub)
+                # rows past the reach of the slot's *reserved* pages go to
+                # the null page; their logits are garbage, so acceptance
+                # stops before them
+                written = {}
+                for slot in stepped:
+                    written[slot] = min(S, pool.reserved_tokens(slot)
+                                        - int(pool.lengths[slot]))
+                    pool.advance(slot, written[slot])
+                if ph is not None:
+                    if self._attn_impl == "paged":
+                        read = int(live_pages(lengths[eff] + 1, S,
+                                              pool.page_size).sum())
+                    else:
+                        read = int(eff.sum()) * pool.max_pages_per_slot
+                    ph.set_metadata(
+                        active=int(eff.sum()), live_tokens=int(lengths.sum()),
+                        kv_pages_read=read,
+                        kv_bytes_read=read * pool.per_device_page_bytes())
+            steps += 1
+            return _Step(steps, out, finite, toks_in, D,
+                         {s: sched.active[s] for s in stepped}, written,
+                         n_act, t_start)
+
+        def finish(f: _Step, nxt: Optional[_Step]) -> None:
+            """Wait on step ``f``'s tokens, count it in the health ladder,
+            then commit it.  ``nxt`` is the step dispatched after ``f`` and
+            still in flight (None in the serial order); a row of ``nxt``
+            whose slot ``f`` faults is dropped and its advance undone."""
+            nonlocal committed_total, slot_steps, pass_tokens, t_done_prev
+            with span("serve.step_wait"):
+                out_np = np.asarray(f.out)
+                finite_np = np.asarray(f.finite)
+            t_done = time.perf_counter()
+            with span("serve.commit"):
+                # rows whose slot lost its request while the step ran
+                # (completed, failed or preempted) or whose input token
+                # was voided commit nothing
+                live = {s: r for s, r in f.rows.items()
+                        if sched.active.get(s) is r and s not in f.dropped}
+                overlap["dropped_rows"] += len(f.rows) - len(live)
+                # the per-step health guard: a stepped slot whose logits
+                # came back non-finite (or was chaos-flagged as such)
+                # commits NOTHING — its length is rolled back, so the
+                # retry recomputes the very same rows deterministically
+                order = [s for s in sched.active if s in live]
+                faulted = {s for s in order if not finite_np[s]}
+                if self.faults is not None:
+                    for slot in order:
+                        if (slot not in faulted
+                                and self.faults.fire("logits.nan")):
+                            faulted.add(slot)
+                # the watchdog's latency: completion to completion, what
+                # a user sees between tokens.  The step counts before its
+                # commit can raise (a window's end in a token list).
+                dt_step = t_done - max(f.t_start, t_done_prev)
+                t_done_prev = t_done
+                self.health.note_step(dt_step, n_slot_faults=len(faulted))
+
+                # acceptance walk: draft i is valid iff it equals the
+                # verify step's argmax after consuming draft i-1 (and
+                # every earlier draft held) — the longest such prefix
+                # commits
+                n_cand = np.zeros((B,), np.int32)
+                slot_steps += len(live)
+                for slot in order:
+                    req = live[slot]
+                    if slot in faulted:
+                        # faulted: the stall contract (no advance, no
+                        # commit, pending untouched) plus retry
+                        # accounting — capped backoff, then terminal
+                        # FAILED with all pages released once the streak
+                        # passes max_retries.  A row of the next step
+                        # consumed this row's token: it goes too.
+                        undo = f.written[slot]
+                        if nxt is not None and nxt.rows.get(slot) is req:
+                            undo += nxt.written[slot]
+                            nxt.dropped.add(slot)
+                        pool.rollback(slot, undo)
+                        req.retries += 1
+                        req.fail_streak += 1
+                        if req.fail_streak > self.health.policy.max_retries:
+                            fail_request(slot, req,
+                                         "non-finite logits past "
+                                         "max_retries")
+                        else:
+                            req.backoff = self.health.policy.backoff(
+                                req.fail_streak)
+                            if tel is not None:
+                                tel.tracer.begin(req.rid, "RETRY_BACKOFF",
+                                                 now(), steps=req.backoff)
+                        continue
+                    req.fail_streak = 0
+                    a = 0
+                    while (a < min(f.depth, f.written[slot] - 1)
+                           and f.toks_in[slot, a + 1] == out_np[slot, a]):
+                        a += 1
+                    n_cand[slot] = a + 1
+                consumed = self._commit_tokens(sched, out_np, n_cand,
+                                               pending, active, now(),
+                                               release_slot)
+                n_tokens = sum(consumed.values())
+                committed_total += n_tokens
+                pass_tokens = (pass_tokens or 0) + n_tokens
+                for slot, c in consumed.items():
+                    if slot in sched.active:  # finished: released
+                        pool.rollback(slot, f.written[slot] - c)
+
+            with span("serve.tap"):
+                # the fallback swaps the step program: only with no step
+                # in flight (the loop drains first when one is due)
+                if nxt is None:
+                    self._apply_health()
+                self._tap_step(f.n_act, n_tokens, dt_step)
+                if tel is not None:
+                    tel.on_step(f.index, dt_step, n_tokens, f.n_act,
+                                pool.allocator.n_free, len(faulted),
+                                self._bucket_class.get(load_bucket(f.n_act),
+                                                       ""))
+
+        def drain():
+            """Wait on the step in flight and commit it before anything
+            else: the serial order."""
+            nonlocal fl
+            overlap["drains"] += 1
+            f, fl = fl, None
+            finish(f, None)
+
         def run_pass(sp) -> bool:
             """One pass of the loop: admission, at most
             ``prefill_chunks_per_step`` prefill chunks, then one pool
-            decode step.  False when nothing is left to serve.  Each
-            phase is a profiler span; ``sp`` is the pass's own (None
-            while no profiler records)."""
-            nonlocal key, steps, committed_total, slot_steps, max_depth
-            nonlocal prev_stall
+            decode step, dispatched before the host waits on the step
+            still in flight and commits it.  False when nothing is left
+            to serve.  Each phase is a profiler span; ``sp`` is the
+            pass's own (None while no profiler records)."""
+            nonlocal fl
             with span("serve.admit") as ph:
                 n_admitted = admit_ready(now())
                 # load shedding right after admission: whatever is STILL
@@ -2094,6 +2325,24 @@ class Engine:
             if sp is not None:
                 sp.set_metadata(chunks=n_chunks)
 
+            # the step in flight fills the budget of some requests: they
+            # sit out the next step (their one token is their last)
+            ending = set()
+            if fl is not None:
+                ending = {s for s, r in fl.rows.items()
+                          if sched.active.get(s) is r and s not in fl.dropped
+                          and len(r.out_tokens) + 1 >= r.max_new_tokens}
+            residents = [s for s in sched.active if s not in ending]
+            # the next step cannot be predicted, or its program may
+            # change (speculation, a re-plan, the fallback), or nothing is
+            # left to step ahead: wait on the step in flight first
+            if fl is not None and (not residents or self._spec_depth
+                                   or self._replan_due(len(residents))
+                                   or self._fallback_due()):
+                drain()
+                ending = set()
+                residents = list(sched.active)
+
             if not sched.active:
                 if prefills:
                     return True             # keep prefilling
@@ -2106,7 +2355,7 @@ class Engine:
                         time.sleep(min(dt, 0.05))
                 return True
 
-            n_act = len(sched.active)
+            n_act = len(residents)
             with span("serve.tap"):
                 self._maybe_replan(n_act)
             t_step0 = time.perf_counter()
@@ -2119,13 +2368,14 @@ class Engine:
             # grow first — consistent with LIFO victim selection — and the
             # oldest may evict past the preempt cap so the head of the
             # line always progresses; everyone else stalls when nothing is
-            # reclaimable.
+            # reclaimable.  With a step in flight the lengths are already
+            # advanced past its rows, so this covers the step after it.
             with span("serve.headroom") as ph:
                 stalled: list[int] = []
                 grown0 = gov.grown_pages
                 cow0 = pool.cow_copies
                 preempts0 = pool.n_preempts
-                order = sorted(sched.active, key=lambda s: (
+                order = sorted(residents, key=lambda s: (
                     sched.active[s].t_admit or 0.0, sched.active[s].rid))
                 for i, slot in enumerate(order):
                     if slot not in sched.active:
@@ -2156,9 +2406,11 @@ class Engine:
                         # stalling keeps it — and never inverts the order
                         # by evicting an older request); the oldest may
                         # override the preempt cap so the head of the
-                        # line always finishes
+                        # line always finishes.  A request the step in
+                        # flight completes is never a victim.
                         victim = gov.pick_victim(
-                            sched.active, ignore_cap=(i == 0),
+                            sched.active, exclude=ending,
+                            ignore_cap=(i == 0),
                             younger_than=(req.t_admit or 0.0, req.rid))
                         if victim is None:
                             stalled.append(slot)
@@ -2169,6 +2421,8 @@ class Engine:
                         # range
                         tel.tracer.instant(req.rid, "COW", now(),
                                            copies=pool.cow_copies - cow0_slot)
+                stepped = [s for s in order
+                           if s in sched.active and s not in stalled]
                 stalled = [s for s in stalled if s in sched.active]
                 if gov.grown_pages != grown0 or pool.cow_copies != cow0:
                     # growth and CoW edit block-table rows in place — the
@@ -2181,150 +2435,38 @@ class Engine:
                                     preempted=pool.n_preempts - preempts0)
             if sp is not None:
                 sp.set_metadata(active=n_act, stalled=len(stalled))
-            if sched.active and len(stalled) == len(sched.active):
+            if not stepped:
                 # every decode is out of pages and nothing is reclaimable:
-                # only resident prefills (whose pages are pre-reserved) can
-                # free the jam by finishing — keep prefilling, skip the step
+                # only the step in flight (completions) or resident
+                # prefills (whose pages are pre-reserved) can free the jam
+                # — keep prefilling, skip the step
                 gov.note_step(len(stalled))
+                if fl is not None:
+                    drain()
                 return True
 
-            with span("serve.step_dispatch") as ph:
-                max_depth = max(max_depth, D)
-                toks_in = np.zeros((B, S), np.int32)
-                toks_in[:, 0] = pending
-                if D:
-                    for slot, req in sched.active.items():
-                        toks_in[slot, 1:] = draft_ngram(req.token_history(),
-                                                        D)
-                key, sub = jax.random.split(key)
-                # expose only non-stalled DECODE slots to the step (null
-                # page otherwise); a stalled slot keeps its pending token
-                # and state untouched and simply retries next step
-                stall_arr = np.zeros((B,), bool)
-                stall_arr[stalled] = True
-                if set(stalled) != prev_stall:
-                    prev_stall = set(stalled)
-                    bt_dev["dirty"] = True
-                eff = active & ~stall_arr
-                if bt_dev["dirty"]:
-                    bt_dev["arr"] = jnp.asarray(
-                        pool.block_tables * eff[:, None])
-                    bt_dev["act"] = jnp.asarray(eff)
-                    bt_dev["dirty"] = False
-                lengths = pool.lengths * eff
-                out, finite, pool.pages = self._pool_step(
-                    self._step_params, pool.pages, jnp.asarray(toks_in),
-                    bt_dev["arr"], jnp.asarray(lengths), bt_dev["act"], sub)
-                if ph is not None:
-                    if self._attn_impl == "paged":
-                        read = int(live_pages(lengths[eff] + 1, S,
-                                              pool.page_size).sum())
-                    else:
-                        read = int(eff.sum()) * pool.max_pages_per_slot
-                    ph.set_metadata(
-                        active=int(eff.sum()), live_tokens=int(lengths.sum()),
-                        kv_pages_read=read,
-                        kv_bytes_read=read * pool.per_device_page_bytes())
+            ahead = fl is not None
+            nxt = dispatch(stepped, n_act, D, fl, t_step0)
+            overlap["steps_ahead"] += ahead
+            if sp is not None:
+                sp.set_metadata(step=nxt.index, ahead=int(ahead))
             if (self.faults is not None
                     and self.faults.fire("step.latency")):
                 # artificial latency spike, inside the step's measured
                 # window so the watchdog (and the tap's reward) sees it
                 time.sleep(self.faults.latency_s)
-            steps += 1
-            if sp is not None:
-                sp.set_metadata(step=steps)
             gov.note_step(len(stalled))
-            with span("serve.step_wait"):
-                out_np = np.asarray(out)
-                finite_np = np.asarray(finite)
-
-            with span("serve.commit"):
-                # the per-step health guard: a stepped slot whose logits
-                # came back non-finite (or was chaos-flagged as such)
-                # commits NOTHING — its lengths never advance, so the
-                # retry recomputes the very same rows deterministically
-                faulted: set[int] = set()
-                for slot in list(sched.active):
-                    if stall_arr[slot] or bool(finite_np[slot]):
-                        continue
-                    faulted.add(slot)
-                if self.faults is not None:
-                    for slot in list(sched.active):
-                        if (not stall_arr[slot] and slot not in faulted
-                                and self.faults.fire("logits.nan")):
-                            faulted.add(slot)
-
-                # acceptance walk: draft i is valid iff it equals the
-                # verify step's argmax after consuming draft i-1 (and
-                # every earlier draft held) — the longest such prefix
-                # commits
-                n_cand = np.ones((B,), np.int32)
-                written = {}
-                slot_steps += len(sched.active) - len(stalled)
-                for slot in list(sched.active):
-                    if stall_arr[slot]:
-                        n_cand[slot] = 0    # sat out: commit nothing
-                        continue
-                    req = sched.active[slot]
-                    if slot in faulted:
-                        # faulted: exactly the stall contract (no advance,
-                        # no commit, pending untouched) plus retry
-                        # accounting — capped backoff, then terminal
-                        # FAILED with all pages released once the streak
-                        # passes max_retries
-                        n_cand[slot] = 0
-                        req.retries += 1
-                        req.fail_streak += 1
-                        if req.fail_streak > self.health.policy.max_retries:
-                            fail_request(slot, req,
-                                         "non-finite logits past "
-                                         "max_retries")
-                        else:
-                            req.backoff = self.health.policy.backoff(
-                                req.fail_streak)
-                            if tel is not None:
-                                tel.tracer.begin(req.rid, "RETRY_BACKOFF",
-                                                 now(), steps=req.backoff)
-                        continue
-                    req.fail_streak = 0
-                    len0 = int(pool.lengths[slot])
-                    # rows past the reach of the slot's *reserved* pages
-                    # went to the null page; their logits are garbage, so
-                    # cap acceptance before them
-                    written[slot] = min(S, pool.reserved_tokens(slot) - len0)
-                    pool.advance(slot, written[slot])
-                    a = 0
-                    while (a < min(D, written[slot] - 1)
-                           and toks_in[slot, a + 1] == out_np[slot, a]):
-                        a += 1
-                    n_cand[slot] = a + 1
-                consumed = self._commit_tokens(sched, out_np, n_cand,
-                                               pending, active, now(),
-                                               release_slot)
-                n_tokens = sum(consumed.values())
-                committed_total += n_tokens
-                for slot, c in consumed.items():
-                    if slot in sched.active:  # finished: released
-                        pool.rollback(slot, written[slot] - c)
-            if sp is not None:
-                sp.set_metadata(tokens=n_tokens)
-
-            with span("serve.tap"):
-                dt_step = time.perf_counter() - t_step0
-                # fold the step into the health ladder, then act on it:
-                # enter the safe-plan fallback while degraded, restore on
-                # recovery
-                self.health.note_step(dt_step, n_slot_faults=len(faulted))
-                if self.health.degraded:
-                    self._enter_fallback()
-                else:
-                    self._exit_fallback()
-                self._tap_step(n_act, n_tokens, dt_step)
-                if tel is not None:
-                    tel.on_step(steps, dt_step, n_tokens, n_act,
-                                pool.allocator.n_free, len(faulted),
-                                self._bucket_class.get(load_bucket(n_act),
-                                                       ""))
+            # the host's work for this pass ran while the device computed
+            # the step in flight; only now wait on it
+            if fl is not None:
+                finish(fl, nxt)
+            if D:
+                # the acceptance walk must precede the next draft: the
+                # serial order
+                fl = None
+                finish(nxt, None)
+            else:
+                fl = nxt
             return True
 
         with span("serve.loop"):
@@ -2336,7 +2478,11 @@ class Engine:
             try:
                 while not sched.done():
                     with span("serve.pass") as sp:
-                        if not run_pass(sp):
+                        pass_tokens = None
+                        more = run_pass(sp)
+                        if sp is not None and pass_tokens is not None:
+                            sp.set_metadata(tokens=pass_tokens)
+                        if not more:
                             break
             except BaseException as e:
                 # engine-internal error mid-serve: the failure domain is
@@ -2364,6 +2510,7 @@ class Engine:
         # (the single aggregate serve() merges in)
         return {"steps": steps,
                 "page_leaks": leaked,
+                "overlap": dict(overlap),
                 "spec": {"committed_tokens": committed_total,
                          "slot_steps": slot_steps,
                          "max_depth": max_depth,

@@ -574,3 +574,253 @@ def test_serve_with_dtree_selects_and_stays_correct(engine):
     assert picked.get("layer/attn") == "attn_blockq_1k"
     for i, r in enumerate(reqs):
         assert r.out_tokens == static[i].tolist()
+
+
+# ---------------------------------------------------------------------------
+# One decode step in flight (spec depth 0): step k+1 is dispatched before
+# the host reads step k's tokens
+# ---------------------------------------------------------------------------
+
+
+def _in_flight_case(event):
+    """(ServeConfig kwargs, prompt rows, prompt length, budgets, whether
+    request 0 stops at an EOS) for one event landing while a step is in
+    flight."""
+    base = dict(max_len=64, max_slots=3, page_size=8, prefill_chunk=4,
+                spec_depth=0)
+    gens = [8, 6, 5]
+    if event == "eos":                  # request 0 stops at an EOS
+        return base, [0, 1, 2], 12, gens, True
+    if event == "budget":               # budgets end at different steps
+        return base, [0, 1, 2], 12, [7, 3, 5], False
+    if event == "prefill_mid_batch":    # prompts join a running batch
+        return base, [0, 1, 2], 12, [9, 6, 5], False
+    if event == "prefix_hit_running":   # a running prompt, served again
+        return (dict(base, page_size=4, prefix_cache="on"), [0, 0, 1], 12,
+                gens, False)
+    if event == "lazy_preempt":         # growth runs dry, a victim goes
+        return (dict(base, max_len=21, prefill_chunk=8, kv_pages=7,
+                     reservation="lazy", mem_watermark=0.0),
+                [0, 1, 2], 4, [16, 16, 16], False)
+    assert event == "logits_nan"        # non-finite rows retry
+    return (dict(base, chaos_rate=0.25, chaos_seed=3,
+                 chaos_sites=("logits.nan",), max_retries=50),
+            [0, 1, 2], 12, gens, False)
+
+
+@pytest.mark.parametrize("event", ["eos", "budget", "prefill_mid_batch",
+                                   "prefix_hit_running", "lazy_preempt",
+                                   "logits_nan"])
+def test_step_in_flight_keeps_greedy_tokens(engine, event):
+    """Each event lands while a step is in flight; every request still
+    commits exactly the static path's greedy tokens, and the pool is
+    whole afterwards."""
+    cfg, eng = engine
+    scfg, rows, plen, gens, eos = _in_flight_case(event)
+    prompts = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(30), (3, plen), 0, cfg.vocab_size,
+        dtype=jnp.int32))[rows]
+    static = np.asarray(eng.generate(jnp.asarray(prompts), max(gens))
+                        ["tokens"])
+    eos_id = int(static[0][2]) if eos else None
+    reqs = [Request(rid=i, prompt=prompts[i], max_new_tokens=g,
+                    eos_id=eos_id if i == 0 else None)
+            for i, g in enumerate(gens)]
+    eng_f = Engine(eng.model, eng.params, serve_cfg=ServeConfig(**scfg))
+    res = eng_f.serve(reqs)
+    for i, r in enumerate(reqs):
+        want = static[i][:gens[i]].tolist()
+        if i == 0 and eos_id is not None:
+            want = want[:want.index(eos_id) + 1]
+        assert r.state is RequestState.DONE, (event, r.rid, r.error)
+        assert r.out_tokens == want, (event, r.rid)
+    pool = eng_f._pool
+    pool.allocator.check_invariants()
+    assert pool.leaked_pages() == 0
+    ov = res["overlap"]
+    assert ov["steps_ahead"] >= 1
+    if event == "budget":
+        assert ov["dropped_rows"] == 0      # budget ends are known ahead
+    if event in ("eos", "lazy_preempt", "logits_nan"):
+        assert ov["dropped_rows"] >= 1      # the ended row was in flight
+    if event == "prefix_hit_running":
+        assert pool.prefix_hit_requests >= 1
+    if event == "lazy_preempt":
+        assert pool.n_preempts >= 1
+    if event == "logits_nan":
+        assert res["faults"]["injected_total"] >= 1
+
+
+class _WindowShut(Exception):
+    pass
+
+
+class _ShutAfter(list):
+    """A request's token list that raises on the ``n``-th append over all
+    requests sharing ``left``, as the benchmark's window does at its end."""
+
+    def __init__(self, left: list):
+        super().__init__()
+        self.left = left
+
+    def append(self, tok):
+        self.left[0] -= 1
+        if self.left[0] < 0:
+            raise _WindowShut()
+        super().append(tok)
+
+
+@pytest.mark.parametrize("n_tokens", [1, 7, 16])
+def test_step_count_survives_a_raise_mid_commit(engine, n_tokens):
+    """A token list that raises mid-commit leaves the pool step's call
+    count at most one ahead of the health ladder's step count (the step
+    in flight), and the pool consistent."""
+    cfg, eng = engine
+    eng_c = Engine(eng.model, eng.params, serve_cfg=ServeConfig(
+        max_len=64, max_slots=3, page_size=8, prefill_chunk=4,
+        spec_depth=0))
+    prompts = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(31), (3, 10), 0, cfg.vocab_size, dtype=jnp.int32))
+    eng_c.serve([Request(rid=9, prompt=prompts[0], max_new_tokens=3)])
+    calls = []
+    step = eng_c._pool_step
+
+    def counted(*args):
+        calls.append(1)
+        return step(*args)
+
+    eng_c._pool_step = counted
+    left = [n_tokens]
+    reqs = []
+    for i in range(3):
+        r = Request(rid=i, prompt=prompts[i], max_new_tokens=8)
+        r.out_tokens = _ShutAfter(left)
+        reqs.append(r)
+    with pytest.raises(_WindowShut):
+        eng_c.serve(reqs)
+    assert len(calls) - eng_c.health.taps["steps"] in (0, 1)
+    eng_c._pool.allocator.check_invariants()
+    assert eng_c._pool.leaked_pages() == 0
+
+
+@pytest.mark.parametrize("depth", [0, 2])
+def test_overlap_counts_steps_ahead(engine, depth):
+    """At depth 0 every step but a few after drains goes ahead; with
+    speculation the acceptance walk precedes the next draft, so none
+    does."""
+    cfg, eng = engine
+    eng_o = Engine(eng.model, eng.params, serve_cfg=ServeConfig(
+        max_len=64, max_slots=3, page_size=8, prefill_chunk=4,
+        spec_depth=depth))
+    prompts = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(32), (4, 10), 0, cfg.vocab_size, dtype=jnp.int32))
+    reqs = [Request(rid=i, prompt=prompts[i], max_new_tokens=g)
+            for i, g in enumerate([9, 4, 6, 7])]
+    res = eng_o.serve(reqs)
+    ov, steps = res["overlap"], res["steps"]
+    if depth == 0:
+        assert ov["steps_ahead"] >= steps - ov["drains"]
+        assert 1 <= ov["drains"] <= 3 and steps - ov["steps_ahead"] <= 3
+    else:
+        assert ov["steps_ahead"] == 0
+    assert res["spec"]["committed_tokens"] == sum(len(r.out_tokens)
+                                                  for r in reqs)
+
+
+class _Spans:
+    """A stand-in for ``telemetry.span`` that keeps each span's name,
+    counters and place in the order, and the open spans' stack."""
+
+    def __init__(self):
+        self.stack: list = []
+        self.opened: list = []
+
+    def __call__(self, name):
+        spans = self
+
+        class _S:
+            meta: dict
+
+            def __enter__(self):
+                self.name, self.meta = name, {}
+                spans.stack.append(name)
+                spans.opened.append(self)
+                return self
+
+            def __exit__(self, *exc):
+                spans.stack.pop()
+
+            def set_metadata(self, **kw):
+                self.meta.update(kw)
+        return _S()
+
+
+class _NumpyReads:
+    """The engine module's ``np``, logging each ``asarray``/``array`` of a
+    device array (on the CPU backend those read through the buffer
+    protocol, not ``jax.Array._value``)."""
+
+    def __init__(self, log):
+        self._log = log
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, a, *args, **kw):
+        if isinstance(a, jax.Array):
+            self._log()
+        return np.asarray(a, *args, **kw)
+
+    def array(self, a, *args, **kw):
+        if isinstance(a, jax.Array):
+            self._log()
+        return np.array(a, *args, **kw)
+
+
+def test_no_read_back_between_a_dispatch_and_the_wait(engine, monkeypatch):
+    """Inside a pass, the only device-to-host reads are the wait on the
+    step in flight; a pass marked ``ahead`` dispatches its step before
+    that wait.  (The CPU backend ignores ``transfer_guard``, so every
+    read of a device array is logged with the spans open at the time.)"""
+    from jax._src import array as jarray
+    from repro.serve import engine as engine_mod
+    cfg, eng = engine
+    eng_t = Engine(eng.model, eng.params, serve_cfg=ServeConfig(
+        max_len=64, max_slots=3, page_size=8, prefill_chunk=4,
+        spec_depth=0))
+    prompts = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(33), (3, 10), 0, cfg.vocab_size, dtype=jnp.int32))
+
+    def mk():
+        return [Request(rid=i, prompt=prompts[i], max_new_tokens=g)
+                for i, g in enumerate([7, 4, 5])]
+    eng_t.serve(mk())                               # compile outside
+    spans = _Spans()
+    reads = []
+    value = jarray.ArrayImpl._value
+
+    def log():
+        reads.append(tuple(spans.stack))
+
+    def logged(arr):
+        log()
+        return value.fget(arr)
+    monkeypatch.setattr(engine_mod, "span", spans)
+    monkeypatch.setattr(engine_mod, "np", _NumpyReads(log))
+    monkeypatch.setattr(jarray.ArrayImpl, "_value", property(logged))
+    res = eng_t.serve(mk())
+    monkeypatch.undo()
+
+    in_pass = [r for r in reads if "serve.pass" in r]
+    assert in_pass and all(r[-1] == "serve.step_wait" for r in in_pass)
+    names = [s.name for s in spans.opened]
+    passes = [i for i, n in enumerate(names) if n == "serve.pass"]
+    ahead = 0
+    for a, b in zip(passes, passes[1:] + [len(names)]):
+        sp = spans.opened[a]
+        if sp.meta.get("ahead"):
+            ahead += 1
+            inner = names[a + 1:b]
+            assert inner.index("serve.step_dispatch") < inner.index(
+                "serve.step_wait")
+    assert ahead == res["overlap"]["steps_ahead"] >= 1
